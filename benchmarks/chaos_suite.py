@@ -44,6 +44,7 @@ except ImportError:                      # run as a script from benchmarks/
 from repro import obs
 from repro.sim.evaluate import (CHAOS_SCENARIOS, chaos_trace_identity,
                                 run_chaos_campaign)
+from repro.compile_cache import enable_compile_cache
 
 REFERENCE_SCENARIO = "node_failure"      # same environment, no chaos
 
@@ -54,6 +55,7 @@ def _compliance_by_job(rows: List[Dict]) -> Dict[str, float]:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scenarios", default=",".join(CHAOS_SCENARIOS))
     ap.add_argument("--jobs", default="lr,mpc,kmeans,gbt")

@@ -7,6 +7,7 @@ from typing import Dict
 import numpy as np
 
 from benchmarks.experiment import get_or_run
+from repro.compile_cache import enable_compile_cache
 
 
 def summarize(job: str, n_adaptive: int = 55, seed: int = 0) -> Dict:
@@ -43,6 +44,7 @@ def render_ascii(job: str, n_adaptive: int = 55, seed: int = 0) -> str:
 
 
 def main(n_adaptive: int = 55):
+    enable_compile_cache()
     for job in ("lr", "mpc", "kmeans", "gbt"):
         s = summarize(job, n_adaptive)
         for method, v in s.items():

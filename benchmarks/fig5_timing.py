@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.dataflow import JOBS, JobExperiment
 from repro.dataflow.runner import HISTORY_WINDOW
+from repro.compile_cache import enable_compile_cache
 
 
 def merge_bench_json(out_path: str, updates: Dict) -> None:
@@ -202,6 +203,7 @@ def measure_decision(job_key: str, seed: int = 0, repeats: int = 5) -> Dict:
 
 
 def main(out_path: str = "BENCH_decision.json"):
+    enable_compile_cache()
     rows = []
     for job in ("lr", "mpc", "kmeans", "gbt"):
         r = measure(job)

@@ -58,6 +58,7 @@ from repro.core.service import DecisionService
 from repro.dataflow import FleetCampaign, JobExperiment
 from repro.dataflow.runner import _component_nodes, _future_nodes, _to_graph
 from repro.sim.engine import SimStepRequest
+from repro.compile_cache import enable_compile_cache
 
 JOB_CYCLE = ("lr", "mpc", "kmeans", "gbt")
 MAX_BUCKETS = 12          # bucket-ladder bound for the 4-job mini-campaign
@@ -350,6 +351,7 @@ def measure_obs_overhead(size: int = 8, n_runs: int = 2, repeats: int = 5,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sizes", default="1,8,32")
     ap.add_argument("--repeats", type=int, default=7)

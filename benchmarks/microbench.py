@@ -8,6 +8,7 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.compile_cache import enable_compile_cache
 
 
 def _time(fn, *args, repeats=3) -> float:
@@ -69,6 +70,7 @@ def train_step_benches(archs=("qwen3-0.6b", "olmoe-1b-7b", "xlstm-350m",
 
 
 def main():
+    enable_compile_cache()
     for r in kernel_benches() + train_step_benches():
         print(f"{r['name']},{r['us']:.0f},interpret_or_smoke")
     return True

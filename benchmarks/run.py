@@ -14,6 +14,8 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 
 def _bench(name: str, fn, derived_fn=lambda r: "ok"):
     t0 = time.time()
@@ -29,6 +31,7 @@ def _bench(name: str, fn, derived_fn=lambda r: "ok"):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="full 55-adaptive-run campaign (slow)")

@@ -47,6 +47,7 @@ from repro.sim.evaluate import (DEFAULT_JOBS, DEFAULT_SCENARIOS,
                                 DEFAULT_TRANSFER_CELLS,
                                 run_scenario_campaign, run_transfer_cells)
 from repro.sim.scenarios import make_scenario
+from repro.compile_cache import enable_compile_cache
 
 JOB_CYCLE = ("lr", "mpc", "kmeans", "gbt")
 
@@ -200,6 +201,7 @@ def measure_fused_race(fleet_size: int = 32, runs: int = 2,
 
 # ----------------------------------------------------------------- driver
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scenarios", default=",".join(DEFAULT_SCENARIOS) +
                     ",multi_tenant")

@@ -5,6 +5,7 @@ from __future__ import annotations
 from typing import Dict, List
 
 from benchmarks.experiment import campaign_window_stats, get_or_run
+from repro.compile_cache import enable_compile_cache
 
 JOBS_ORDER = ["lr", "mpc", "kmeans", "gbt"]
 
@@ -31,6 +32,7 @@ def render(table: Dict) -> str:
 
 
 def main(n_adaptive: int = 55):
+    enable_compile_cache()
     table = run(n_adaptive=n_adaptive)
     print(render(table))
     return table

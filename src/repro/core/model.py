@@ -172,6 +172,11 @@ def _propagate(params, x, adj, m_obs, valid,
     w_m = w0[EDGE_DIM:]
     f4_tail = params["f4"][1:]
 
+    # rematerialized under autodiff: the backward pass keeps only each
+    # level's (N, M) input instead of its (N, N, HIDDEN) activations — the
+    # fused campaign's in-scan fit at 1024 tenants otherwise needs ~16 GB of
+    # temporaries on a 16 GiB v5e
+    @jax.checkpoint
     def level_step(_, m_cur):
         mj = jnp.where(valid[:, None], m_obs, m_cur)            # (N, M)
         hidden = jax.nn.leaky_relu(pre_h + (mj @ w_m)[None, :, :] + b0, 0.1)
@@ -307,23 +312,17 @@ def _sweep_impl(params, base, h_onehot, deltas, use_kernel, levels):
     return total["total_runtime"].reshape(c, k)
 
 
-_sweep_jit = jax.jit(_sweep_impl, static_argnums=(4, 5))
-# deltas are rebuilt host-side every decision -> safe to donate off-CPU
-_sweep_jit_donated = jax.jit(_sweep_impl, static_argnums=(4, 5),
-                             donate_argnums=(3,))
-
-
-@functools.lru_cache(maxsize=1)
-def _sweep_fn():
-    return _sweep_jit if jax.default_backend() == "cpu" else _sweep_jit_donated
+# deltas are uploaded fresh for every decision, so their buffers are donated
+_sweep_jit = jax.jit(_sweep_impl, static_argnums=(4, 5), donate_argnums=(3,))
 
 
 def sweep_per_component(params: Dict, base: Dict, h_onehot, deltas,
                         use_kernel: Optional[bool] = None,
                         levels: int = MAX_LEVELS) -> jax.Array:
-    """Jitted batched candidate sweep -> per-component totals (C, K)."""
-    return _sweep_fn()(params, base, h_onehot, deltas,
-                       graph_prop_kernel_enabled(use_kernel), levels)
+    """Jitted batched candidate sweep -> per-component totals (C, K).
+    ``deltas`` is donated: pass device arrays nobody else holds."""
+    return _sweep_jit(params, base, h_onehot, deltas,
+                      graph_prop_kernel_enabled(use_kernel), levels)
 
 
 # ---------------------------------------------------- sparse-edge sweep engine

@@ -19,7 +19,6 @@ Two fit routes share the loss/optimizer math:
 """
 from __future__ import annotations
 
-import functools
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -131,17 +130,11 @@ def _adam_run_impl(params, opt, batch, steps, lr, use_kernel=False):
     return params, opt, losses[-1], steps - jnp.sum(oks)
 
 
-_adam_run = jax.jit(_adam_run_impl, static_argnums=(3, 5))
 # params/opt are replaced by the returned pytrees every call -> donating their
-# buffers avoids a copy per fit; donation is a no-op (warning) on CPU, so the
-# donated variant is only selected off-CPU.
-_adam_run_donated = jax.jit(_adam_run_impl, static_argnums=(3, 5),
-                            donate_argnums=(0, 1))
-
-
-@functools.lru_cache(maxsize=1)
-def _adam_run_fn():
-    return _adam_run if jax.default_backend() == "cpu" else _adam_run_donated
+# buffers avoids a copy per fit.  Nothing may keep the old trainer.params /
+# opt leaves for later use: they are deleted by the call.
+_adam_run = jax.jit(_adam_run_impl, static_argnums=(3, 5),
+                    donate_argnums=(0, 1))
 
 
 def _adam_run_resident_impl(params, opt, batch, weights, key, lr, dropout_p,
@@ -167,18 +160,10 @@ def _adam_run_resident_impl(params, opt, batch, weights, key, lr, dropout_p,
     return params, opt, losses[-1], steps - jnp.sum(oks)
 
 
-_adam_run_resident = jax.jit(_adam_run_resident_impl, static_argnums=(7, 8))
 # batch/weights live in the TrainingCache and MUST NOT be donated; params/opt
-# follow the same replace-every-call pattern as the legacy run.
-_adam_run_resident_donated = jax.jit(_adam_run_resident_impl,
-                                     static_argnums=(7, 8),
-                                     donate_argnums=(0, 1))
-
-
-@functools.lru_cache(maxsize=1)
-def _adam_run_resident_fn():
-    return _adam_run_resident if jax.default_backend() == "cpu" \
-        else _adam_run_resident_donated
+# follow the same replace-every-call (donated) pattern as the legacy run.
+_adam_run_resident = jax.jit(_adam_run_resident_impl, static_argnums=(7, 8),
+                             donate_argnums=(0, 1))
 
 
 def _round_steps(steps: int) -> int:
@@ -267,7 +252,7 @@ class EnelTrainer:
                        for k in stacked}
         batch = {k: jnp.asarray(v) for k, v in stacked.items()}
         steps = _round_steps(steps)
-        self.params, self.opt, loss, skipped = _adam_run_fn()(
+        self.params, self.opt, loss, skipped = _adam_run(
             self.params, self.opt, batch, steps, self.lr,
             enel_model.graph_prop_kernel_enabled())
         self._note_skipped(skipped, steps)
@@ -329,7 +314,7 @@ class EnelTrainer:
         self._fit_calls += 1
         use_kernel = enel_model.graph_prop_kernel_enabled()
         n_steps = _round_steps(steps)
-        self.params, self.opt, loss, skipped = _adam_run_resident_fn()(
+        self.params, self.opt, loss, skipped = _adam_run_resident(
             self.params, self.opt, batch, jnp.asarray(weights), key, self.lr,
             float(metric_dropout), n_steps, use_kernel)
         self._note_skipped(skipped, n_steps)

@@ -1,8 +1,10 @@
 """Jit-friendly wrapper: Enel param pytree + bool masks -> fused kernel.
 
-Handles batch padding to the graph-block size, dtype/bias-layout massaging
-and the interpret-mode fallback (the CPU backend cannot lower TPU Pallas, so
-off-TPU the kernel runs in interpret mode — same semantics, used by tests).
+Handles batch padding to the graph-block size and dtype/bias-layout
+massaging.  On a TPU both kernels compile through Mosaic; on the CPU backend,
+which cannot lower TPU Pallas, they run in interpret mode (same semantics —
+the test suite's route).  Any other backend raises instead of silently
+interpreting.
 
 The wrapped op carries a ``jax.custom_vjp``: the backward pass is a second
 Pallas kernel (:func:`repro.kernels.graph_prop.kernel.graph_prop_bwd_kernel`)
@@ -71,7 +73,11 @@ def graph_prop(params: Dict, x: jax.Array, adj: jax.Array, m_obs: jax.Array,
     ``params``, ``x`` and ``m_obs`` via the backward Pallas kernel.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        if backend not in ("cpu", "tpu"):
+            raise NotImplementedError(
+                f"graph_prop has no lowering for the {backend!r} backend")
+        interpret = backend == "cpu"
     b = x.shape[0]
     gb = min(block_g, b)
     pad = (-b) % gb

@@ -57,8 +57,7 @@ def make_dp_train_step(cfg: ModelConfig, opt: AdamWConfig, mesh: Mesh,
         return jax.tree_util.tree_map(lambda _: sharded, tree)
 
     def step_fn(state, err_state, batch):
-        from repro.train.shard_compat import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree_util.tree_map(lambda _: replicated, state),
                       jax.tree_util.tree_map(lambda _: replicated, err_state),
@@ -66,7 +65,7 @@ def make_dp_train_step(cfg: ModelConfig, opt: AdamWConfig, mesh: Mesh,
             out_specs=(jax.tree_util.tree_map(lambda _: replicated, state),
                        jax.tree_util.tree_map(lambda _: replicated,
                                               err_state),
-                       replicated))
+                       replicated), check_vma=False)
         return fn(state, err_state, batch)
 
     def init_extra(params) -> Dict:

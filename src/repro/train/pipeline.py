@@ -58,8 +58,7 @@ def pipelined_forward(params: Dict[str, jax.Array], x: jax.Array,
         _, outputs = jax.lax.fori_loop(0, ticks, tick, (recv0, out0))
         return jax.lax.psum(outputs, axis)   # non-last stages contribute 0
 
-    from repro.train.shard_compat import shard_map
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(None, None, None)),
-                   out_specs=P(None, None, None))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(None, None, None)),
+                       out_specs=P(None, None, None), check_vma=False)
     return fn(params, x)

@@ -10,6 +10,8 @@ The contracts under test:
 * the template device cache is a bounded LRU;
 * the on-device pick replicates the host pick's tie-breaking.
 """
+import copy
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -292,3 +294,32 @@ def test_pick_candidate_matches_host_pick():
                                  jnp.asarray(totals), jnp.asarray(target)))
         assert valid[idx]
         assert float(cand[idx]) == host_s
+
+
+# ------------------------------------------------------- buffer donation
+def test_fit_donation_leaves_no_stale_holder(fleet_exps):
+    """Fits donate the trainer's params/opt buffers on every backend, so
+    nothing may serve the replaced leaves afterwards: a request prepared
+    after the fit carries live params, and the service's identity-keyed
+    stack memo restacks them instead of reusing the stack of the old ones."""
+    exp = fleet_exps[0]
+    # a deep copy: on CPU np.asarray views device memory, and a buffer with
+    # a live host view is never donated
+    saved = copy.deepcopy(exp.trainer.snapshot_state())
+    try:
+        kw = _decision_kwargs(exp)
+        svc = DecisionService()
+        svc.decide([exp.enel.prepare_request(**kw)])   # memo holds old params
+        old = jax.tree_util.tree_leaves((exp.trainer.params,
+                                         exp.trainer.opt[:2]))
+        exp.trainer.fit_resident(steps=8, latest_only=True)
+        assert all(leaf.is_deleted() for leaf in old)
+        req = exp.enel.prepare_request(**kw)
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree_util.tree_leaves(req.params))
+        res = svc.decide([req])[0]
+        fresh = DecisionService().decide([req])[0]
+        assert not res.fallback and res.scaleout == fresh.scaleout
+        assert res.totals == fresh.totals
+    finally:
+        exp.trainer.restore_state(saved)
