@@ -161,97 +161,101 @@ def _step(st: PlanStatic, dev, carry, t):
     comp_ok = dev["comp_valid"][k]                       # (J,)
 
     # ---------------------------------------------- (a) fleet sim step
-    # device twin of tables.overhead_f32 (anti-FMA guarded like the engine)
-    d = jnp.abs(z - a)
-    ov = jnp.where(a == z, f32(0.0), f32(4.0) + _nc(f32(0.35) * d))
-    ctrl = jnp.stack([clock, carry["interf"], a, z, dev["inject"],
-                      dev["n_stage_f"][k], ov, dev["cursor_f"][k]], axis=-1)
-    state, outs = _step_kernel_impl(
-        dev["blocks"][r], ctrl, st.s_max, dev["kills"][r], dev["burst"],
-        dev["preempt"], dev["iscale2"], dev["mem_tab"], dev["shuf_tab"])
-    clock = state[:, 0]                                  # pass-through when
-    interf = state[:, 1]                                 # comp invalid (n=0)
+    with jax.named_scope("enel.sim"):
+        # device twin of tables.overhead_f32 (anti-FMA guarded like the
+        # engine)
+        d = jnp.abs(z - a)
+        ov = jnp.where(a == z, f32(0.0), f32(4.0) + _nc(f32(0.35) * d))
+        ctrl = jnp.stack([clock, carry["interf"], a, z, dev["inject"],
+                          dev["n_stage_f"][k], ov, dev["cursor_f"][k]],
+                         axis=-1)
+        state, outs = _step_kernel_impl(
+            dev["blocks"][r], ctrl, st.s_max, dev["kills"][r], dev["burst"],
+            dev["preempt"], dev["iscale2"], dev["mem_tab"], dev["shuf_tab"])
+        clock = state[:, 0]                              # pass-through when
+        interf = state[:, 1]                             # comp invalid (n=0)
 
     # ------------------------------------- (b) observed ring row, on device
-    g = dev["cls"]
-    h = dev["hcls"]
-    rmask = dev["row_mask"][g, k]                        # (J, N_ROW)
-    rsum = dev["row_summ"][g, k]
-    radj = dev["row_adj"][g, k]                          # (J, N_ROW, N_ROW)
-    rsi = dev["row_stage_idx"][g, k]                     # (J, N_ROW) i32
-    rst = dev["row_is_stage"][g, k]
-    rs0 = rst & (rsi == 0)
-    rp = dev["row_is_p"][g, k]
-    rh = dev["row_is_h"][g, k]
+    with jax.named_scope("enel.ring"):
+        g = dev["cls"]
+        h = dev["hcls"]
+        rmask = dev["row_mask"][g, k]                        # (J, N_ROW)
+        rsum = dev["row_summ"][g, k]
+        radj = dev["row_adj"][g, k]                      # (J, N_ROW, N_ROW)
+        rsi = dev["row_stage_idx"][g, k]                     # (J, N_ROW) i32
+        rst = dev["row_is_stage"][g, k]
+        rs0 = rst & (rsi == 0)
+        rp = dev["row_is_p"][g, k]
+        rh = dev["row_is_h"][g, k]
 
-    pm, pa, pz = carry["p_met"], carry["p_a"], carry["p_z"]
-    km1 = jnp.maximum(k - 1, 0)
-    ctx_k = dev["obs_ctx"][g, k]                         # (J, S, NS, CTX)
-    ctx_kz = ctx_k[ji[:, None], rsi, zi(z)[:, None]]     # (J, N_ROW, CTX)
-    p_ctx_old = dev["p_ctx"][g, km1, zi(pz)]             # (J, CTX)
-    h_ctx = dev["hob_ctx"][h, k, zi(z)]                  # (J, CTX)
-    h_met = dev["hob_met"][h, k, zi(z)]                  # (J, N_METRICS)
-    h_val = dev["hob_val"][h, k, zi(z)]                  # (J,)
-    h_a = dev["hob_start"][h, k, zi(z)]
-    h_b = dev["hob_end"][h, k, zi(z)]
+        pm, pa, pz = carry["p_met"], carry["p_a"], carry["p_z"]
+        km1 = jnp.maximum(k - 1, 0)
+        ctx_k = dev["obs_ctx"][g, k]                         # (J, S, NS, CTX)
+        ctx_kz = ctx_k[ji[:, None], rsi, zi(z)[:, None]]     # (J, N_ROW, CTX)
+        p_ctx_old = dev["p_ctx"][g, km1, zi(pz)]             # (J, CTX)
+        h_ctx = dev["hob_ctx"][h, k, zi(z)]                  # (J, CTX)
+        h_met = dev["hob_met"][h, k, zi(z)]                  # (J, N_METRICS)
+        h_val = dev["hob_val"][h, k, zi(z)]                  # (J,)
+        h_a = dev["hob_start"][h, k, zi(z)]
+        h_b = dev["hob_end"][h, k, zi(z)]
 
-    met_js = jnp.swapaxes(outs[:, :, _O_MET], 0, 1)      # (J, S, 5)
-    rt_js = jnp.swapaxes(outs[:, :, _O_RT], 0, 1)        # (J, S)
-    row_met = met_js[ji[:, None], rsi]                   # (J, N_ROW, 5)
-    row_rt = rt_js[ji[:, None], rsi]                     # (J, N_ROW)
+        met_js = jnp.swapaxes(outs[:, :, _O_MET], 0, 1)      # (J, S, 5)
+        rt_js = jnp.swapaxes(outs[:, :, _O_RT], 0, 1)        # (J, S)
+        row_met = met_js[ji[:, None], rsi]                   # (J, N_ROW, 5)
+        row_rt = rt_js[ji[:, None], rsi]                     # (J, N_ROW)
 
-    a2, z2 = a[:, None], z[:, None]
-    rescale0 = rs0 & (a2 != z2)
-    w3 = lambda m: m[..., None]
-    row = {
-        "context": (jnp.where(w3(rst), ctx_kz, 0.0)
-                    + jnp.where(w3(rp), p_ctx_old[:, None, :], 0.0)
-                    + jnp.where(w3(rh), h_ctx[:, None, :], 0.0)),
-        "metrics": (jnp.where(w3(rst), row_met, 0.0)
-                    + jnp.where(w3(rp), pm[:, None, :], 0.0)
-                    + jnp.where(w3(rh), h_met[:, None, :], 0.0)),
-        "metrics_valid": rst | rp | (rh & h_val[:, None]),
-        "a_raw": jnp.where(rs0, a2, jnp.where(rst, z2, jnp.where(
-            rp, pa[:, None], jnp.where(rh, h_a[:, None], 1.0)))),
-        "z_raw": jnp.where(rst, z2, jnp.where(
-            rp, pz[:, None], jnp.where(rh, h_b[:, None], 1.0))),
-        "r": jnp.where(rescale0, f32(0.8), f32(1.0)),
-        "runtime": jnp.where(rst, row_rt, 0.0),
-        "runtime_valid": rst,
-        "overhead": jnp.where(rescale0, ov[:, None], 0.0),
-        "overhead_valid": rescale0,
-        "adj": radj,
-        "mask": rmask,
-        "is_summary": rsum,
-    }
+        a2, z2 = a[:, None], z[:, None]
+        rescale0 = rs0 & (a2 != z2)
+        w3 = lambda m: m[..., None]
+        row = {
+            "context": (jnp.where(w3(rst), ctx_kz, 0.0)
+                        + jnp.where(w3(rp), p_ctx_old[:, None, :], 0.0)
+                        + jnp.where(w3(rh), h_ctx[:, None, :], 0.0)),
+            "metrics": (jnp.where(w3(rst), row_met, 0.0)
+                        + jnp.where(w3(rp), pm[:, None, :], 0.0)
+                        + jnp.where(w3(rh), h_met[:, None, :], 0.0)),
+            "metrics_valid": rst | rp | (rh & h_val[:, None]),
+            "a_raw": jnp.where(rs0, a2, jnp.where(rst, z2, jnp.where(
+                rp, pa[:, None], jnp.where(rh, h_a[:, None], 1.0)))),
+            "z_raw": jnp.where(rst, z2, jnp.where(
+                rp, pz[:, None], jnp.where(rh, h_b[:, None], 1.0))),
+            "r": jnp.where(rescale0, f32(0.8), f32(1.0)),
+            "runtime": jnp.where(rst, row_rt, 0.0),
+            "runtime_valid": rst,
+            "overhead": jnp.where(rescale0, ov[:, None], 0.0),
+            "overhead_valid": rescale0,
+            "adj": radj,
+            "mask": rmask,
+            "is_summary": rsum,
+        }
 
-    ring = carry["ring"]
-    cap = ring["slot_ok"].shape[1]
+        ring = carry["ring"]
+        cap = ring["slot_ok"].shape[1]
 
-    def _append(bufs, row_j, pos_j, ok_j, slot_ok_j):
-        old = jax.tree_util.tree_map(lambda b: b[pos_j], bufs)
-        sel = jax.tree_util.tree_map(
-            lambda nv, ovv: jnp.where(ok_j, nv.astype(ovv.dtype), ovv),
-            row_j, old)
-        bufs = ring_append(bufs, sel, pos_j)
-        slot_ok_j = slot_ok_j.at[pos_j].set(
-            jnp.where(ok_j, True, slot_ok_j[pos_j]))
-        return bufs, slot_ok_j
+        def _append(bufs, row_j, pos_j, ok_j, slot_ok_j):
+            old = jax.tree_util.tree_map(lambda b: b[pos_j], bufs)
+            sel = jax.tree_util.tree_map(
+                lambda nv, ovv: jnp.where(ok_j, nv.astype(ovv.dtype), ovv),
+                row_j, old)
+            bufs = ring_append(bufs, sel, pos_j)
+            slot_ok_j = slot_ok_j.at[pos_j].set(
+                jnp.where(ok_j, True, slot_ok_j[pos_j]))
+            return bufs, slot_ok_j
 
-    buffers, slot_ok = jax.vmap(_append)(
-        ring["buffers"], row, ring["pos"], comp_ok, ring["slot_ok"])
-    inc = comp_ok.astype(jnp.int32)
-    pos = (ring["pos"] + inc) % cap
-    count = jnp.minimum(ring["count"] + inc, cap)
+        buffers, slot_ok = jax.vmap(_append)(
+            ring["buffers"], row, ring["pos"], comp_ok, ring["slot_ok"])
+        inc = comp_ok.astype(jnp.int32)
+        pos = (ring["pos"] + inc) % cap
+        count = jnp.minimum(ring["count"] + inc, cap)
 
-    # fresh P(k) summary (current_summary for this boundary's decision)
-    nst = dev["n_stage_f"][k].astype(jnp.int32)
-    sv = jnp.arange(st.s_max)[None, :] < nst[:, None]    # (J, S)
-    pm_new = (jnp.sum(jnp.where(sv[..., None], met_js, 0.0), axis=1)
-              / jnp.maximum(nst, 1)[:, None].astype(f32))
-    pm = jnp.where(comp_ok[:, None], pm_new, pm)
-    pa = jnp.where(comp_ok, a, pa)
-    pz = jnp.where(comp_ok, z, pz)
+        # fresh P(k) summary (current_summary for this boundary's decision)
+        nst = dev["n_stage_f"][k].astype(jnp.int32)
+        sv = jnp.arange(st.s_max)[None, :] < nst[:, None]    # (J, S)
+        pm_new = (jnp.sum(jnp.where(sv[..., None], met_js, 0.0), axis=1)
+                  / jnp.maximum(nst, 1)[:, None].astype(f32))
+        pm = jnp.where(comp_ok[:, None], pm_new, pm)
+        pa = jnp.where(comp_ok, a, pa)
+        pz = jnp.where(comp_ok, z, pz)
 
     # ------------------------------------ (c) decision sweep + guardrails
     decide = dev["decide_tab"][k]                        # (J,)
@@ -323,8 +327,9 @@ def _step(st: PlanStatic, dev, carry, t):
     def _no_sweep(_):
         return s_cur, jnp.ones(J, bool)
 
-    s_new, dec_ok = jax.lax.cond(dev["any_decide"][k], _run_sweep,
-                                 _no_sweep, None)
+    with jax.named_scope("enel.sweep"):
+        s_new, dec_ok = jax.lax.cond(dev["any_decide"][k], _run_sweep,
+                                     _no_sweep, None)
     fb_used = decide & ~dec_ok
     nonfin = decide & ~jnp.isfinite(s_new)
     s_next = jnp.where(decide, s_new, s_cur)
@@ -371,8 +376,9 @@ def _step(st: PlanStatic, dev, carry, t):
     def _no_fit(_):
         return params, opt, jnp.zeros(J, f32), jnp.zeros(J, jnp.int32)
 
-    params, opt, fit_loss, fit_skip = jax.lax.cond(is_last, _do_fit,
-                                                   _no_fit, None)
+    with jax.named_scope("enel.fit"):
+        params, opt, fit_loss, fit_skip = jax.lax.cond(is_last, _do_fit,
+                                                       _no_fit, None)
     fcalls = jnp.where(is_last, fcalls + 1, fcalls)
 
     # nan_fit chaos fires right after the fit, exactly like the live hook

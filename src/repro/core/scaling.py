@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import time
 from collections import OrderedDict, defaultdict
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -62,6 +63,8 @@ GraphBuilder = Callable[[int, float, float, List[NodeAttrs]], ComponentGraph]
 A_PROBE = 1.0e5
 Z_PROBE = 2.0e5
 H_SLOT = "__H__"          # placeholder name marking the H-summary node slot
+
+_request_ids = itertools.count()    # DecisionRequest.rid allocator
 
 
 class _TemplateDeviceCache:
@@ -385,30 +388,54 @@ class EnelScaler:
         arrays are swapped for the device-resident cache copies.  Returns
         ``None`` when there is nothing left to decide.
         """
-        candidates = self.candidate_scaleouts(current_scaleout)
         if next_comp >= n_components:
             return None
-        template, deltas = self.build_sweep(
-            graph_builder=graph_builder, next_comp=next_comp,
-            n_components=n_components, current_scaleout=current_scaleout,
-            candidates=candidates, current_summary=current_summary)
-        template, deltas, (c_real, k_real) = bucket_sweep(template, deltas)
-        c_b = deltas["a_raw"].shape[0]
-        # keyed by the REAL remaining-component count too: decision points
-        # sharing a K rung but differing in real adj/mask must not thrash
-        # one slot (identity-stable edges keep the service stack memo warm)
-        ekey = (k_real,) + template.base["mask"].shape
-        cached = self._edge_cache.get(ekey)
-        if cached is not None and \
-                np.array_equal(cached[0], template.base["adj"]) and \
-                np.array_equal(cached[1], template.base["mask"]):
-            edge_dst, edge_src, edge_valid = cached[2]
-        else:
-            edges = sweep_edge_list(template.base)
-            self._edge_cache[ekey] = (template.base["adj"].copy(),
-                                      template.base["mask"].copy(), edges)
-            edge_dst, edge_src, edge_valid = edges
-        template = self.template_cache.adopt(template, c_b)
+        rid = next(_request_ids)
+        with obs.span("enel.prep", rid=rid, tenant=self.trainer.obs_name):
+            req = self._prepare(
+                graph_builder=graph_builder, next_comp=next_comp,
+                n_components=n_components, elapsed=elapsed,
+                current_scaleout=current_scaleout,
+                target_runtime=target_runtime,
+                current_summary=current_summary, best_effort=best_effort)
+        req.rid = rid
+        req.prepared_at = time.perf_counter()
+        return req
+
+    def _prepare(self, *, graph_builder, next_comp, n_components, elapsed,
+                 current_scaleout, target_runtime, current_summary,
+                 best_effort) -> DecisionRequest:
+        candidates = self.candidate_scaleouts(current_scaleout)
+        with obs.span("enel.prep.build"):
+            template, deltas = self.build_sweep(
+                graph_builder=graph_builder, next_comp=next_comp,
+                n_components=n_components,
+                current_scaleout=current_scaleout, candidates=candidates,
+                current_summary=current_summary)
+        cache = self.template_cache
+        moved0 = (cache.transfers, cache.skips)
+        with obs.span("enel.prep.adopt") as sp:
+            template, deltas, (c_real, k_real) = bucket_sweep(template,
+                                                              deltas)
+            c_b = deltas["a_raw"].shape[0]
+            # keyed by the REAL remaining-component count too: decision
+            # points sharing a K rung but differing in real adj/mask must
+            # not thrash one slot (identity-stable edges keep the service
+            # stack memo warm)
+            ekey = (k_real,) + template.base["mask"].shape
+            cached = self._edge_cache.get(ekey)
+            if cached is not None and \
+                    np.array_equal(cached[0], template.base["adj"]) and \
+                    np.array_equal(cached[1], template.base["mask"]):
+                edge_dst, edge_src, edge_valid = cached[2]
+            else:
+                edges = sweep_edge_list(template.base)
+                self._edge_cache[ekey] = (template.base["adj"].copy(),
+                                          template.base["mask"].copy(), edges)
+                edge_dst, edge_src, edge_valid = edges
+            template = cache.adopt(template, c_b)
+            sp.set(transfers=cache.transfers - moved0[0],
+                   skips=cache.skips - moved0[1])
         ckey = (c_b,) + tuple(candidates)
         if ckey in self._cand_cache:
             cand_arr, cand_valid = self._cand_cache[ckey]

@@ -89,10 +89,14 @@ def _job_bucket(j: int) -> int:
     return ladder_bucket(j, JOB_LADDER)
 
 
-def _stack_leaves(*xs):
-    """Host leaves: one np.stack + one upload; device leaves: jnp.stack."""
+def _stack_leaves(*xs, tally=None):
+    """Host leaves: one np.stack + one upload; device leaves: jnp.stack.
+    ``tally["bytes"]`` counts the bytes uploaded."""
     if isinstance(xs[0], np.ndarray):
-        return jnp.asarray(np.stack(xs))
+        host = np.stack(xs)
+        if tally is not None:
+            tally["bytes"] += host.nbytes
+        return jnp.asarray(host)
     return jnp.stack(xs)
 
 
@@ -106,7 +110,9 @@ class DecisionRequest:
 
     ``current_scaleout`` carries the requester's live allocation so a
     fallback answer can step FROM somewhere; ``best_effort`` marks requests
-    the service may shed first under overload.
+    the service may shed first under overload.  ``rid`` names the request
+    in the ``enel.prep`` span and any ``decision.fallback`` event;
+    ``prepared_at`` starts its ``enel_decision_latency_seconds`` sample.
     """
     params: Dict                      # this tenant's model parameters
     base: Dict                        # (K, N, ...) template arrays
@@ -124,6 +130,8 @@ class DecisionRequest:
     n_components: int                 # real K (pre-padding)
     current_scaleout: int = 0         # requester's live allocation
     best_effort: bool = False         # sheddable under overload
+    rid: int = -1                     # request id (prep span, fallbacks)
+    prepared_at: Optional[float] = None  # perf_counter at prep's return
 
     @property
     def bucket_key(self):
@@ -390,17 +398,20 @@ class DecisionService:
     def breaker_trips(self) -> int:
         return self.breaker.trips
 
-    def _stack_tree(self, cache_key: tuple, rows, get):
+    def _stack_tree(self, cache_key: tuple, rows, get, tally):
         trees = [get(r) for r in rows]
         all_leaves = [jax.tree_util.tree_leaves(t) for t in trees]
         ids = tuple(id(l) for row in all_leaves for l in row)
         hit = self._stack_memo.get(cache_key)
         if hit is not None and hit[0] == ids:
             self._stack_memo.move_to_end(cache_key)
+            tally["hits"] += 1
             return hit[2]
+        tally["misses"] += 1
         treedef = jax.tree_util.tree_structure(trees[0])
         stacked = jax.tree_util.tree_unflatten(
-            treedef, [_stack_leaves(*col) for col in zip(*all_leaves)])
+            treedef, [_stack_leaves(*col, tally=tally)
+                      for col in zip(*all_leaves)])
         # keep the leaf refs alive so the memo's ids cannot be recycled
         self._stack_memo[cache_key] = (ids, all_leaves, stacked)
         while len(self._stack_memo) > self._stack_memo_slots:
@@ -413,28 +424,25 @@ class DecisionService:
             self.fault_injector()       # chaos: may raise DispatchFault
         j_b = _job_bucket(len(group))
         rows = group + [group[-1]] * (j_b - len(group))
-        stack = lambda get: jax.tree_util.tree_map(
-            _stack_leaves, *[get(r) for r in rows])
-        out = _fleet_jit(
-            self._stack_tree((key, j_b, "params"), rows,
-                             lambda r: r.params),
-            self._stack_tree((key, j_b, "base"), rows, lambda r: r.base),
-            self._stack_tree((key, j_b, "h_onehot"), rows,
-                             lambda r: r.h_onehot),
-            stack(lambda r: r.deltas),
-            self._stack_tree((key, j_b, "edge_dst"), rows,
-                             lambda r: r.edge_dst),
-            self._stack_tree((key, j_b, "edge_src"), rows,
-                             lambda r: r.edge_src),
-            self._stack_tree((key, j_b, "edge_valid"), rows,
-                             lambda r: r.edge_valid),
-            self._stack_tree((key, j_b, "candidates"), rows,
-                             lambda r: r.candidates),
-            self._stack_tree((key, j_b, "cand_valid"), rows,
-                             lambda r: r.cand_valid),
-            jnp.asarray([r.elapsed for r in rows], jnp.float32),
-            jnp.asarray([r.target for r in rows], jnp.float32),
-            group[0].levels)
+        tally = {"hits": 0, "misses": 0, "bytes": 0}
+        with obs.span("enel.decide.stack") as sp:
+            memo = lambda field: self._stack_tree(
+                (key, j_b, field), rows, lambda r: getattr(r, field), tally)
+            stacked = [memo("params"), memo("base"), memo("h_onehot")]
+            deltas = jax.tree_util.tree_map(
+                lambda *xs: _stack_leaves(*xs, tally=tally),
+                *[r.deltas for r in rows])
+            stacked += [deltas] + [memo(field) for field in (
+                "edge_dst", "edge_src", "edge_valid", "candidates",
+                "cand_valid")]
+            for field in ("elapsed", "target"):
+                host = np.asarray([getattr(r, field) for r in rows],
+                                  np.float32)
+                tally["bytes"] += host.nbytes
+                stacked.append(jnp.asarray(host))
+            sp.set(**tally)
+        with obs.span("enel.decide.launch"):
+            out = _fleet_jit(*stacked, group[0].levels)
         self.dispatches += 1
         self.batched_away += len(group) - 1
         return out
@@ -468,7 +476,8 @@ class DecisionService:
         if shed:
             self.shed_requests += 1
         obs.emit("decision.fallback", service=self.obs_name, cause=cause,
-                 cause_seq=cause_seq, shed=shed, scaleout=int(s),
+                 cause_seq=cause_seq, rid=req.rid, shed=shed,
+                 scaleout=int(s),
                  from_scaleout=int(req.current_scaleout))
         return res
 
@@ -517,6 +526,12 @@ class DecisionService:
 
     def decide(self, requests: Sequence[DecisionRequest]
                ) -> List[DecisionResult]:
+        with obs.span("enel.decide", _ring=True,
+                      requests=len(requests)) as sp:
+            return self._decide(requests, sp)
+
+    def _decide(self, requests: Sequence[DecisionRequest], sp
+                ) -> List[DecisionResult]:
         t_start = time.time()
         results: List[Optional[DecisionResult]] = [None] * len(requests)
         live = self._shed(requests, results)
@@ -529,6 +544,7 @@ class DecisionService:
         groups: Dict[tuple, List[int]] = defaultdict(list)
         for i in live:
             groups[requests[i].bucket_key].append(i)
+        sp.set(groups=len(groups))
         deadline = self.deadline_s
         staged = []
         dispatch_ok = True
@@ -544,19 +560,20 @@ class DecisionService:
                 continue
             if not self.double_buffer:
                 # synchronous mode: fetch before stacking the next bucket
-                out = (jax.device_get((out[0], out[1], out[3])), out[2])
+                with obs.span("enel.decide.fetch"):
+                    out = (jax.device_get((out[0], out[1], out[3])), out[2])
             staged.append((idxs, key, retried, out))
         for idxs, key, retried, out in staged:
             if self.double_buffer:
                 picked, totals, per, ok = out
                 # ONE host transfer per group: picks + totals + ok flags
-                picked_np, totals_np, ok_np = jax.device_get(
-                    (picked, totals, ok))
+                with obs.span("enel.decide.fetch"):
+                    picked_np, totals_np, ok_np = jax.device_get(
+                        (picked, totals, ok))
             else:
                 (picked_np, totals_np, ok_np), per = out
             obs.emit("decision.dispatch", service=self.obs_name,
-                     bucket=str(key), group=len(idxs), retries=retried,
-                     latency_s=round(time.time() - t_start, 6))
+                     bucket=str(key), group=len(idxs), retries=retried)
             for gi, ri in enumerate(idxs):
                 req = requests[ri]
                 if not bool(ok_np[gi]):     # guardrail: poisoned sweep row
@@ -587,10 +604,13 @@ class DecisionService:
             if obs.enabled():
                 hist = obs.registry().histogram(
                     "enel_decision_latency_seconds",
-                    "per-request share of decide() wall time"
+                    "from a request's prepare_request return to the end "
+                    "of the decide() that answered it"
                 ).labels(service=self.obs_name)
-                for _ in requests:
-                    hist.observe(share)
+                now = time.perf_counter()
+                for r in requests:
+                    if r.prepared_at is not None:
+                        hist.observe(now - r.prepared_at)
         return results
 
     # ----------------------------------------------------------- telemetry

@@ -19,7 +19,6 @@ Two fit routes share the loss/optimizer math:
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import itertools
@@ -185,7 +184,6 @@ class EnelTrainer:
         self.params = enel_model.init_enel(jax.random.PRNGKey(seed))
         self._reset_opt()
         self.runs_seen = 0
-        self.last_fit_seconds = 0.0
         # device-resident history ring for the online fast path (lazy: sized
         # to the first graphs seen); legacy fit() keeps working without it
         self.cache: Optional[TrainingCache] = None
@@ -206,11 +204,14 @@ class EnelTrainer:
         obs.emit("fit", trainer=self.obs_name, route=route,
                  mode="scratch" if scratch else "tune", steps=steps,
                  skipped=self.last_skipped_steps, retried=retried,
-                 loss=round(float(loss), 6),
-                 seconds=round(self.last_fit_seconds, 6))
-        obs.observe("enel_fit_seconds", self.last_fit_seconds,
-                    trainer=self.obs_name,
-                    mode="scratch" if scratch else "tune")
+                 loss=round(float(loss), 6))
+
+    def _fit_span(self, route: str, scratch: bool, steps: int) -> obs.Span:
+        """The ``enel.fit`` ring span around one fit, closed after its
+        loss is on the host."""
+        return obs.span("enel.fit", _ring=True, trainer=self.obs_name,
+                        route=route, mode="scratch" if scratch else "tune",
+                        steps=_round_steps(steps))
 
     def _reset_opt(self):
         zeros = jax.tree_util.tree_map(jnp.zeros_like, self.params)
@@ -231,7 +232,10 @@ class EnelTrainer:
         """
         if not graphs:
             return float("nan")
-        t0 = time.time()
+        with self._fit_span("legacy", from_scratch, steps):
+            return self._fit(graphs, steps, from_scratch, metric_dropout)
+
+    def _fit(self, graphs, steps, from_scratch, metric_dropout) -> float:
         if from_scratch:
             self.params = enel_model.init_enel(jax.random.PRNGKey(self.seed))
             self._reset_opt()
@@ -256,7 +260,6 @@ class EnelTrainer:
             self.params, self.opt, batch, steps, self.lr,
             enel_model.graph_prop_kernel_enabled())
         self._note_skipped(skipped, steps)
-        self.last_fit_seconds = time.time() - t0
         loss = float(loss)
         self._emit_fit("legacy", from_scratch, steps, loss)
         return loss
@@ -303,7 +306,12 @@ class EnelTrainer:
         """
         if self.cache is None or self.cache.count == 0:
             return float("nan")
-        t0 = time.time()
+        with self._fit_span("resident", from_scratch, steps):
+            return self._fit_resident(steps, from_scratch, metric_dropout,
+                                      latest_only, _retry)
+
+    def _fit_resident(self, steps, from_scratch, metric_dropout,
+                      latest_only, _retry) -> float:
         if from_scratch:
             self.params = enel_model.init_enel(jax.random.PRNGKey(self.seed))
             self._reset_opt()
@@ -318,7 +326,6 @@ class EnelTrainer:
             self.params, self.opt, batch, jnp.asarray(weights), key, self.lr,
             float(metric_dropout), n_steps, use_kernel)
         self._note_skipped(skipped, n_steps)
-        self.last_fit_seconds = time.time() - t0
         if self.last_skipped_steps >= n_steps and _retry and \
                 self.params_finite() and \
                 self.cache.quarantine_nonfinite() > 0:

@@ -213,7 +213,8 @@ class FleetCampaign:
         pending: Dict[int, object] = {}
         for i, gen in list(gens.items()):
             try:
-                pending[i] = next(gen)
+                with obs.span("enel.resume"):
+                    pending[i] = next(gen)
             except StopIteration as stop:       # run without any request
                 stats[i] = stop.value
         return pending
@@ -233,17 +234,26 @@ class FleetCampaign:
         ``i`` — the checkpoint event log.  Returns (next pending,
         capped-decision count, ids of generators that finished this round).
         """
-        results: Dict[int, object] = {}
         sims = {i: r for i, r in pending.items()
                 if isinstance(r, SimStepRequest)}
         decs = {i: r for i, r in pending.items() if i not in sims}
+        with obs.span("enel.round", _ring=True, sims=len(sims),
+                      decisions=len(decs)):
+            return self._round_body(gens, sims, decs, stats, caps,
+                                    on_decision, on_result)
+
+    def _round_body(self, gens, sims, decs, stats, caps, on_decision,
+                    on_result):
+        results: Dict[int, object] = {}
         by_backend: Dict[int, List[int]] = {}
         for i in sims:
             by_backend.setdefault(
                 id(self.experiments[i].backend), []).append(i)
         for ids in by_backend.values():
             backend = self.experiments[ids[0]].backend
-            for i, res in zip(ids, backend.step([sims[i] for i in ids])):
+            with obs.span("enel.sim_step", _ring=True, sims=len(ids)):
+                out = backend.step([sims[i] for i in ids])
+            for i, res in zip(ids, out):
                 results[i] = res
         capped = 0
         if decs:
@@ -266,7 +276,8 @@ class FleetCampaign:
             if on_result is not None:
                 on_result(i, res)
             try:
-                nxt[i] = gens[i].send(res)
+                with obs.span("enel.resume"):
+                    nxt[i] = gens[i].send(res)
             except StopIteration as stop:
                 stats[i] = stop.value
                 done.append(i)

@@ -1,20 +1,25 @@
-"""Flight recorder: a bounded ring of structured span events.
+"""Flight recorder: a bounded ring of point events and spans.
 
-Spans are plain dicts ``{"seq", "ts", "kind", "attrs"}``. ``seq`` is a
-monotonic index (causal links between spans reference it — e.g. a
-``decision.fallback`` span carries ``cause_seq`` pointing at the
-guardrail/timeout/breaker event that forced it). ``ts`` is wall time
-for live spans and a *logical* timestamp (sim clock) for spans replayed
-from fused-campaign telemetry, so fused and stepped replays of the same
-plan produce identical streams modulo ``seq``/``ts`` — parity compares
-``(kind, attrs)``.
+Entries are plain dicts.  A point event is ``{"seq", "ts", "kind",
+"parent", "attrs"}``; a span (``obs.span(..., _ring=True)``) is ``{"seq",
+"kind", "start", "end", "parent", "attrs"}``, appended when it opens and
+given its ``end`` when it closes (``None`` while open).  ``seq`` is a
+monotonic index (causal links reference it — e.g. a ``decision.fallback``
+event carries ``cause_seq`` pointing at the guardrail/timeout/breaker
+event that forced it); ``parent`` is the seq of the ring span that was
+open around the entry (-1: none).  ``ts``/``start``/``end`` are wall time
+for live entries; events replayed from fused-campaign telemetry carry a
+*logical* timestamp (the step index), so fused and stepped replays of the
+same plan produce identical streams modulo ``seq``/``ts`` — parity
+compares ``(kind, attrs)``.
 
-The ring is bounded (default 4096 spans): old spans fall off, the
+The ring is bounded (default 4096 entries): old entries fall off, the
 recorder never grows without bound inside long campaigns.
 """
 from __future__ import annotations
 
 import json
+import time
 from collections import deque
 from typing import Callable, Dict, Iterable, List, Optional
 
@@ -30,24 +35,34 @@ class FlightRecorder:
 
     # -- emission -----------------------------------------------------
 
-    def emit(self, _kind: str, _ts: Optional[float] = None, **attrs) -> int:
-        """Append a span; returns its seq (-1 when gated off).
+    def _append(self, entry: Dict) -> Dict:
+        entry["seq"] = self._seq
+        self._seq += 1
+        if len(self._ring) == self.capacity:
+            self.dropped += 1
+        self._ring.append(entry)
+        return entry
 
-        The positional params are underscore-prefixed so span attrs named
+    def emit(self, _kind: str, _ts: Optional[float] = None,
+             _parent: int = -1, **attrs) -> int:
+        """Append a point event; returns its seq (-1 when gated off).
+
+        The positional params are underscore-prefixed so event attrs named
         ``kind``/``ts`` (e.g. a run's scaler kind) stay usable as kwargs.
         """
         if self.gate is not None and not self.gate():
             return -1
         if _ts is None:
-            import time
             _ts = time.time()
-        seq = self._seq
-        self._seq += 1
-        if len(self._ring) == self.capacity:
-            self.dropped += 1
-        self._ring.append({"seq": seq, "ts": float(_ts), "kind": str(_kind),
-                           "attrs": attrs})
-        return seq
+        return self._append({"ts": float(_ts), "kind": str(_kind),
+                             "parent": int(_parent), "attrs": attrs})["seq"]
+
+    def open_span(self, kind: str, start: float, parent: int,
+                  attrs: Dict) -> Dict:
+        """Append an open span (the caller sets ``end`` when it closes);
+        returns the ring entry.  Not gated: ``obs.span`` gates."""
+        return self._append({"kind": kind, "start": start, "end": None,
+                             "parent": parent, "attrs": attrs})
 
     # -- queries ------------------------------------------------------
 

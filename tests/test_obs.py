@@ -12,10 +12,13 @@ Layers under test:
 * neutrality: with observability disabled a campaign's decisions are
   bit-exact vs the enabled twin and the timed reruns add zero jit traces;
 * fused == stepped span parity: replaying the two drivers' (bit-exact)
-  telemetry outputs yields identical span streams.
+  telemetry outputs yields identical span streams;
+* host spans: nesting and ``parent``, self time, phase spans kept out of
+  the ring, the gate, and the two latency histograms they replaced.
 """
 import json
 import math
+import time
 
 import numpy as np
 import pytest
@@ -276,3 +279,164 @@ def test_fallback_spans_link_to_cause():
         if at["cause_seq"] >= 0:
             cause = rec.find(at["cause_seq"])
             assert cause is not None and cause["seq"] < ev["seq"]
+
+
+# ------------------------------------------------------------- host spans
+
+def _kmeans_request(exp=None):
+    """A profiled kmeans tenant (``exp``, or a fresh one) and one request."""
+    if exp is None:
+        exp = JobExperiment("kmeans", seed=2, candidate_stride=4)
+        exp.profile(2)
+    from repro.dataflow.runner import _future_nodes, _to_graph
+    builder = lambda ci, a, z, pr: _to_graph(
+        _future_nodes(exp.encoder, exp.job, ci, a, z), pr, ci)
+    req = exp.enel.prepare_request(
+        graph_builder=builder, next_comp=1,
+        n_components=exp.job.n_components, elapsed=10.0,
+        current_scaleout=8, target_runtime=exp.target)
+    return exp, req
+
+
+def test_span_nesting_parent_and_self_time():
+    rec = obs.recorder()
+    rec.clear()
+    with obs.obs_enabled(True):
+        with obs.span("t.outer", _ring=True, a=1) as outer:
+            with obs.span("t.phase"):            # profiler + histogram only
+                inner_ev = obs.emit("t.event", x=2)
+            with obs.span("t.inner", _ring=True) as inner:
+                time.sleep(0.01)
+                inner.set(hits=3)
+            time.sleep(0.02)
+        after = obs.emit("t.after")
+    spans = {e["kind"]: e for e in rec.events() if "end" in e}
+    assert set(spans) == {"t.outer", "t.inner"}     # t.phase stays out
+    o, i = spans["t.outer"], spans["t.inner"]
+    assert o["seq"] == outer.seq and i["seq"] == inner.seq
+    assert o["parent"] == -1 and i["parent"] == o["seq"]
+    assert rec.find(inner_ev)["parent"] == o["seq"]  # phase spans skipped
+    assert rec.find(after)["parent"] == -1
+    assert i["attrs"] == {"hits": 3} and o["attrs"] == {"a": 1}
+    assert o["start"] <= i["start"] <= i["end"] <= o["end"]
+    # self time: the outer span's own 20 ms sleep, its child's excluded
+    self_s = (o["end"] - o["start"]) - (i["end"] - i["start"])
+    assert 0.02 <= self_s < 0.2 and i["end"] - i["start"] >= 0.01
+    hist = obs.registry().get("enel_span_seconds")
+    for kind in ("t.outer", "t.inner", "t.phase"):
+        assert hist.labels(span=kind).count >= 1
+
+
+def test_disabled_obs_records_no_span():
+    rec = obs.recorder()
+    rec.clear()
+    hist = obs.registry().histogram("enel_span_seconds")
+    with obs.obs_enabled(False):
+        with obs.span("t.off", _ring=True) as sp:
+            assert obs.emit("t.off.event") == -1
+            sp.set(n=1)
+    assert sp.seq == -1 and len(rec) == 0
+    assert hist.labels(span="t.off").count == 0
+    assert obs.current_span() == -1
+
+
+def test_disabled_obs_decide_is_bit_exact_and_trace_neutral():
+    """ENEL_OBS=0 on the request path: twin tenants get the same pick and
+    totals with and without observability, the disabled one leaves no
+    recorder entry or span sample, and it adds no jit trace beyond what
+    an enabled twin adds on the same warmed caches."""
+    def decide_twin():
+        counts0 = dict(enel_model.TRACE_COUNTS)
+        _, req = _kmeans_request()
+        res = DecisionService().decide([req])[0]
+        delta = {k: v - counts0.get(k, 0)
+                 for k, v in enel_model.TRACE_COUNTS.items()
+                 if v != counts0.get(k, 0)}
+        return (res.scaleout, res.predicted, res.totals), delta
+
+    with obs.obs_enabled(True):
+        decide_twin()                   # warm every shape
+        on, delta_on = decide_twin()
+    rec = obs.recorder()
+    rec.clear()
+    hist = obs.registry().histogram("enel_span_seconds")
+    n0 = hist.labels(span="enel.decide").count
+    with obs.obs_enabled(False):
+        off, delta_off = decide_twin()
+    assert off == on and delta_off == delta_on
+    assert len(rec) == 0 and hist.labels(span="enel.decide").count == n0
+
+
+def test_decision_latency_histogram_times_each_request():
+    """``enel_decision_latency_seconds`` runs from a request's prep to the
+    end of the decide() that answered it, not a share of decide()."""
+    exp, req = _kmeans_request()
+    _, req2 = _kmeans_request(exp)
+    assert req2.rid > req.rid >= 0 and req.prepared_at is not None
+    svc = DecisionService(obs_name="t_latency")
+    svc.decide([req2])                  # warm the shapes
+    time.sleep(0.05)
+    t0 = time.perf_counter()
+    with obs.obs_enabled(True):
+        res = svc.decide([req])
+    wall = time.perf_counter() - t0
+    h = obs.registry().get("enel_decision_latency_seconds").labels(
+        service="t_latency")
+    assert h.count == 2
+    assert h.vmax >= 0.05 + wall * 0.9          # waited before decide()
+    assert res[0].service_seconds <= wall       # the share keeps its meaning
+
+
+def test_fit_span_replaces_fit_seconds_histogram():
+    exp = JobExperiment("lr", seed=4, candidate_stride=4)
+    rec = obs.recorder()
+    with obs.obs_enabled(True):
+        rec.clear()
+        exp.profile(2)                  # ends in a resident scratch fit
+    assert obs.registry().get("enel_fit_seconds") is None
+    fits = [e for e in rec.events("enel.fit")]
+    assert fits and fits[-1]["attrs"]["mode"] == "scratch"
+    assert fits[-1]["attrs"]["steps"] == 128
+    ev = rec.events("fit")[-1]
+    assert ev["parent"] == fits[-1]["seq"] and "seconds" not in ev["attrs"]
+    assert obs.registry().get("enel_span_seconds").labels(
+        span="enel.fit").count >= 1
+
+
+def test_fallback_cause_resolves_after_a_live_unit():
+    """A live-style unit of requests (three tenants, lockstep rounds, one
+    adaptive run each) with the first dispatch failing: the ring keeps
+    layer spans only, so the fallback's cause is still in it afterwards,
+    and the fallback names its request and its enclosing decide() span."""
+    from repro.core.service import DispatchTimeout
+    rec = obs.recorder()
+    with obs.obs_enabled(True):
+        rec.clear()
+        exps = [JobExperiment(k, seed=60 + i, candidate_stride=4)
+                for i, k in enumerate(("lr", "kmeans", "gbt"))]
+        svc = DecisionService(obs_name="t_unit", max_retries=0)
+        camp = FleetCampaign(exps, svc, engine="batched")
+        camp.profile(2)
+        state = {"n": 0}
+
+        def first_fails():
+            state["n"] += 1
+            if state["n"] == 1:
+                raise DispatchTimeout("injected")
+
+        svc.fault_injector = first_fails
+        camp.adaptive_round()
+    kinds = set(rec.span_counts())
+    assert not kinds & {"enel.prep", "enel.prep.build", "enel.prep.adopt",
+                        "enel.decide.stack", "enel.decide.launch",
+                        "enel.decide.fetch", "enel.resume"}
+    assert {"enel.round", "enel.sim_step", "enel.decide",
+            "enel.fit"} <= kinds
+    falls = rec.events("decision.fallback")
+    assert falls and rec.dropped == 0
+    for ev in falls:
+        at = ev["attrs"]
+        assert at["cause"] == "retries_exhausted" and at["rid"] >= 0
+        cause = rec.find(at["cause_seq"])
+        assert cause is not None and cause["kind"] == "dispatch.fault"
+        assert rec.find(ev["parent"])["kind"] == "enel.decide"
