@@ -89,15 +89,44 @@ def _job_bucket(j: int) -> int:
     return ladder_bucket(j, JOB_LADDER)
 
 
-def _stack_leaves(*xs, tally=None):
-    """Host leaves: one np.stack + one upload; device leaves: jnp.stack.
-    ``tally["bytes"]`` counts the bytes uploaded."""
-    if isinstance(xs[0], np.ndarray):
-        host = np.stack(xs)
-        if tally is not None:
+def _group_stack_impl(rows):
+    """Stack each device leaf along a new leading job axis: ``rows`` is the
+    group's padded list of per-row leaf lists, the result one list of
+    (J, ...) arrays.  One compiled call per memo field, not one eager
+    ``jnp.stack`` (J + 1 dispatches) per leaf; the compile key is the
+    field's leaf shapes and the job rung J, never the group's real size."""
+    record_trace("group_stack")
+    return [jnp.stack(col) for col in zip(*rows)]
+
+
+_group_stack = jax.jit(_group_stack_impl)
+
+
+def _stack_rows(treedef, leaf_rows, tally):
+    """Stack per-row leaf lists (``leaf_rows``, one per padded row, all of
+    ``treedef``) into one tree of (J, ...) arrays.
+
+    Host leaves get one np.stack + one upload each; device leaves are
+    stacked together in one ``_group_stack`` call.  ``tally["bytes"]``
+    counts the bytes uploaded, ``tally["launches"]`` the uploads and
+    compiled calls issued."""
+    out = []
+    dev = []
+    for i, col in enumerate(zip(*leaf_rows)):
+        if isinstance(col[0], np.ndarray):
+            host = np.stack(col)
             tally["bytes"] += host.nbytes
-        return jnp.asarray(host)
-    return jnp.stack(xs)
+            tally["launches"] += 1
+            out.append(jnp.asarray(host))
+        else:
+            dev.append(i)
+            out.append(None)
+    if dev:
+        tally["launches"] += 1
+        stacked = _group_stack([[row[i] for i in dev] for row in leaf_rows])
+        for i, x in zip(dev, stacked):
+            out[i] = x
+    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 @dataclasses.dataclass
@@ -408,10 +437,8 @@ class DecisionService:
             tally["hits"] += 1
             return hit[2]
         tally["misses"] += 1
-        treedef = jax.tree_util.tree_structure(trees[0])
-        stacked = jax.tree_util.tree_unflatten(
-            treedef, [_stack_leaves(*col, tally=tally)
-                      for col in zip(*all_leaves)])
+        stacked = _stack_rows(jax.tree_util.tree_structure(trees[0]),
+                              all_leaves, tally)
         # keep the leaf refs alive so the memo's ids cannot be recycled
         self._stack_memo[cache_key] = (ids, all_leaves, stacked)
         while len(self._stack_memo) > self._stack_memo_slots:
@@ -424,14 +451,14 @@ class DecisionService:
             self.fault_injector()       # chaos: may raise DispatchFault
         j_b = _job_bucket(len(group))
         rows = group + [group[-1]] * (j_b - len(group))
-        tally = {"hits": 0, "misses": 0, "bytes": 0}
+        tally = {"hits": 0, "misses": 0, "bytes": 0, "launches": 0}
         with obs.span("enel.decide.stack") as sp:
             memo = lambda field: self._stack_tree(
                 (key, j_b, field), rows, lambda r: getattr(r, field), tally)
             stacked = [memo("params"), memo("base"), memo("h_onehot")]
-            deltas = jax.tree_util.tree_map(
-                lambda *xs: _stack_leaves(*xs, tally=tally),
-                *[r.deltas for r in rows])
+            deltas = _stack_rows(
+                jax.tree_util.tree_structure(rows[0].deltas),
+                [jax.tree_util.tree_leaves(r.deltas) for r in rows], tally)
             stacked += [deltas] + [memo(field) for field in (
                 "edge_dst", "edge_src", "edge_valid", "candidates",
                 "cand_valid")]
@@ -439,6 +466,7 @@ class DecisionService:
                 host = np.asarray([getattr(r, field) for r in rows],
                                   np.float32)
                 tally["bytes"] += host.nbytes
+                tally["launches"] += 1
                 stacked.append(jnp.asarray(host))
             sp.set(**tally)
         with obs.span("enel.decide.launch"):

@@ -8,9 +8,12 @@ The contracts under test:
 * one batched service dispatch over a multi-job fleet returns exactly the
   decisions the jobs would get from sequential per-job ``recommend``;
 * the template device cache is a bounded LRU;
+* a group's request stack is one compiled call per memo field, equal to
+  the per-leaf stack bit for bit and compiled once per job rung;
 * the on-device pick replicates the host pick's tie-breaking.
 """
 import copy
+import types
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +26,7 @@ from repro.core.graph import (CTX_DIM, N_METRICS, NodeAttrs, SweepTemplate,
                               summary_node, sweep_edge_list)
 from repro.core.model import pick_candidate, sweep_sparse_totals
 from repro.core.scaling import EnelScaler, _TemplateDeviceCache
+from repro.core import service as service_mod
 from repro.core.service import DecisionService
 from repro.dataflow import FleetCampaign, JobExperiment
 from repro.dataflow.runner import (_component_nodes, _future_nodes, _to_graph)
@@ -323,3 +327,82 @@ def test_fit_donation_leaves_no_stale_holder(fleet_exps):
         assert res.totals == fresh.totals
     finally:
         exp.trainer.restore_state(saved)
+
+
+# ------------------------------------------------- compiled group stacking
+def _device_rows(n):
+    """``n`` request-like rows with device params, base and h_onehot, as the
+    scaler's template cache hands them to the service."""
+    rows = []
+    for i in range(n):
+        tmpl = _mini_template(3, seed=i)
+        rows.append(types.SimpleNamespace(
+            params=enel_model.init_enel(jax.random.PRNGKey(i)),
+            base={k: jnp.asarray(v) for k, v in tmpl.base.items()},
+            h_onehot=jnp.asarray(tmpl.h_onehot)))
+    return rows
+
+
+@pytest.mark.parametrize("n_real", [1, 3, 8])
+def test_group_stack_equals_per_leaf_stack(n_real):
+    """A memo miss stacks a field's device leaves in one compiled call that
+    equals the per-leaf ``jnp.stack`` bit for bit, padding included; a hit
+    returns the stored stack and traces nothing."""
+    group = _device_rows(n_real)
+    j_b = service_mod._job_bucket(n_real)
+    rows = group + [group[-1]] * (j_b - n_real)
+    svc = DecisionService()
+    tally = {"hits": 0, "misses": 0, "bytes": 0, "launches": 0}
+    fields = ("params", "base", "h_onehot")
+    got = {f: svc._stack_tree(("k", j_b, f), rows,
+                              lambda r, f=f: getattr(r, f), tally)
+           for f in fields}
+    assert tally == {"hits": 0, "misses": 3, "bytes": 0, "launches": 3}
+    for f in fields:
+        trees = [getattr(r, f) for r in rows]
+        want = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+        assert (jax.tree_util.tree_structure(got[f])
+                == jax.tree_util.tree_structure(want))
+        for a, b in zip(jax.tree_util.tree_leaves(got[f]),
+                        jax.tree_util.tree_leaves(want)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    traces = enel_model.trace_count("group_stack")
+    for f in fields:
+        again = svc._stack_tree(("k", j_b, f), rows,
+                                lambda r, f=f: getattr(r, f), tally)
+        assert again is got[f]
+    assert tally["hits"] == 3 and tally["launches"] == 3
+    assert enel_model.trace_count("group_stack") == traces
+
+
+def test_group_stack_compiles_stay_bounded():
+    """Groups of different real sizes in one job rung share one compiled
+    stack per field, and a second same-shape campaign round traces neither
+    the stack nor the sweep again (the window's zero-compile rule)."""
+    exps = [JobExperiment("lr", seed=60 + i) for i in range(4)]
+    camp = FleetCampaign(exps)
+    camp.profile(2)
+    camp.adaptive_round("enel", inject_failures=False)      # warm-up round
+    svc = camp.service
+    decide = svc.decide
+    seen = []
+
+    def decide_with_subgroup(reqs):
+        # the first three of a four-request group alone: real size 3 in the
+        # same rung of 4, answered exactly as inside the full group
+        if len(reqs) == 4:
+            part = decide(reqs[:3])
+            full = decide(reqs)
+            for a, b in zip(part, full):
+                assert a.scaleout == b.scaleout and a.totals == b.totals
+            seen.append(len(reqs))
+            return full
+        return decide(reqs)
+
+    svc.decide = decide_with_subgroup
+    before = dict(enel_model.TRACE_COUNTS)
+    camp.adaptive_round("enel", inject_failures=False)
+    assert seen                             # sizes 3 and 4 both stacked
+    for name in ("group_stack", "fleet_sweep"):
+        assert enel_model.TRACE_COUNTS[name] == before.get(name, 0), name
