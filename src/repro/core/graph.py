@@ -464,6 +464,19 @@ def _jit_helper(name: str, fn):
     return f
 
 
+def _copy_tree_impl(tree):
+    import jax
+    import jax.numpy as jnp
+    return jax.tree_util.tree_map(lambda x: jnp.array(x, copy=True), tree)
+
+
+def copy_tree(tree):
+    """``tree``'s leaves copied into new device buffers in one compiled
+    call (a jitted identity would hand back its inputs; the copy primitive
+    does not)."""
+    return _jit_helper("copy", _copy_tree_impl)(tree)
+
+
 def append_stacked(buffers: Dict, rows: Dict, idx) -> Dict:
     """Scatter freshly-stacked rows into the device ring buffers at ``idx``
     (jitted; one compile per rows-per-append shape)."""
@@ -604,6 +617,15 @@ class TrainingCache:
         w[:m] = self.slot_ok[self.latest]
         return _jit_helper("gather", _gather_rows_impl)(
             self.buffers, jnp.asarray(idx)), w
+
+    def copy(self) -> "TrainingCache":
+        """This ring with its buffers in new device buffers."""
+        out = TrainingCache.__new__(TrainingCache)
+        out.__dict__.update(self.__dict__)
+        out.buffers = copy_tree(self.buffers)
+        out.latest = self.latest.copy()
+        out.slot_ok = self.slot_ok.copy()
+        return out
 
     # --------------------------------------------------- checkpoint support
     def snapshot(self) -> Dict:

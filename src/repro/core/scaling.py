@@ -198,6 +198,18 @@ class EnelScaler:
         self._edge_cache: Dict[Tuple[int, int, int], Tuple] = {}
         self._cand_cache: Dict[Tuple, Tuple[np.ndarray, np.ndarray]] = {}
 
+    def copy(self, trainer: EnelTrainer) -> "EnelScaler":
+        """A scaler serving ``trainer`` with this one's observation history
+        (summaries, first-component pairs, structural probes); its device
+        template cache starts empty."""
+        out = EnelScaler(trainer, self.range, self.beta,
+                         self.candidate_stride)
+        out.hist_summaries = defaultdict(
+            list, {k: list(v) for k, v in self.hist_summaries.items()})
+        out.first_component_history = list(self.first_component_history)
+        out._probe_cache = dict(self._probe_cache)
+        return out
+
     @property
     def last_per_component(self) -> Optional[np.ndarray]:
         """(C, K) per-component predictions of the last sweep (lazy fetch)."""

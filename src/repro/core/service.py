@@ -71,6 +71,10 @@ _SERVICE_COUNTERS = {
                           "failed dispatch attempts (incl. retried)"),
     "shed_requests": ("enel_service_shed_requests_total",
                       "requests rejected under overload"),
+    "memo_lookups": ("enel_stack_memo_lookups_total",
+                     "stack-memo lookups, one per memoised field a group"),
+    "memo_hits": ("enel_stack_memo_hits_total",
+                  "stack-memo lookups that reused a stacked field"),
 }
 
 _BREAKER_STATE_CODE = {"closed": 0, "half_open": 1, "open": 2}
@@ -179,12 +183,15 @@ class DecisionResult:
 
     ``fallback``/``shed`` flag decisions the model did not make: answered
     by the heuristic policy (guardrail trip, breaker open, retries
-    exhausted) or rejected under overload, respectively.
+    exhausted) or rejected under overload, respectively.  ``rid`` names
+    the request it answers, so a result delivered to the wrong requester
+    can be told apart.
     """
 
     def __init__(self, scaleout: int, predicted: float,
                  totals: Dict[int, float], per_component_dev,
-                 n_candidates: int, n_components: int):
+                 n_candidates: int, n_components: int, rid: int = -1):
+        self.rid = rid                  # the answered request's rid
         self.scaleout = scaleout
         self.predicted = predicted
         self.totals = totals
@@ -363,7 +370,8 @@ class CircuitBreaker:
 class DecisionService:
     """Collects concurrent decision requests and dispatches them batched.
 
-    ``decide`` groups requests by bucket key, pads each group to a JOB_LADDER
+    ``decide`` groups requests by bucket key (at most ``JOB_LADDER[-1]`` to a
+    group; a larger bucket splits), pads each group to a JOB_LADDER
     rung along the job axis, evaluates every group in one jit dispatch and
     fetches each group's picks + totals in a single host transfer.
 
@@ -432,9 +440,11 @@ class DecisionService:
         all_leaves = [jax.tree_util.tree_leaves(t) for t in trees]
         ids = tuple(id(l) for row in all_leaves for l in row)
         hit = self._stack_memo.get(cache_key)
+        self.memo_lookups += 1
         if hit is not None and hit[0] == ids:
             self._stack_memo.move_to_end(cache_key)
             tally["hits"] += 1
+            self.memo_hits += 1
             return hit[2]
         tally["misses"] += 1
         stacked = _stack_rows(jax.tree_util.tree_structure(trees[0]),
@@ -497,7 +507,7 @@ class DecisionService:
             totals=self.fallback._finite_totals(req.candidate_list, totals),
             per_component_dev=None,
             n_candidates=len(req.candidate_list),
-            n_components=req.n_components)
+            n_components=req.n_components, rid=req.rid)
         res.fallback = True
         res.shed = shed
         self.fallback_decisions += 1
@@ -531,7 +541,7 @@ class DecisionService:
                 sleep *= 0.5 + self._rng.rand()     # seeded jitter
                 if attempt >= self.max_retries or (
                         deadline is not None and
-                        time.time() - t_start + sleep > deadline):
+                        time.perf_counter() - t_start + sleep > deadline):
                     return None, attempt, fault_seq
                 time.sleep(sleep)
                 self.retries += 1
@@ -560,7 +570,7 @@ class DecisionService:
 
     def _decide(self, requests: Sequence[DecisionRequest], sp
                 ) -> List[DecisionResult]:
-        t_start = time.time()
+        t_start = time.perf_counter()
         results: List[Optional[DecisionResult]] = [None] * len(requests)
         live = self._shed(requests, results)
         if live and not self.breaker.allow():       # open: fallback for all
@@ -569,14 +579,19 @@ class DecisionService:
                     requests[i], cause="breaker_open",
                     cause_seq=self.breaker.last_transition_seq)
             live = []
-        groups: Dict[tuple, List[int]] = defaultdict(list)
+        by_key: Dict[tuple, List[int]] = defaultdict(list)
         for i in live:
-            groups[requests[i].bucket_key].append(i)
+            by_key[requests[i].bucket_key].append(i)
+        # a group fills at most the top rung: a larger bucket splits, so a
+        # burst never pads to a shape past the ladder (a new compile)
+        cap = JOB_LADDER[-1]
+        groups = [(key, idxs[lo:lo + cap]) for key, idxs in by_key.items()
+                  for lo in range(0, len(idxs), cap)]
         sp.set(groups=len(groups))
         deadline = self.deadline_s
         staged = []
         dispatch_ok = True
-        for key, idxs in groups.items():
+        for key, idxs in groups:
             out, retried, fault_seq = self._dispatch_with_retry(
                 key, [requests[i] for i in idxs], t_start, deadline)
             if out is None:                         # envelope exhausted
@@ -621,12 +636,12 @@ class DecisionService:
                     predicted=float(totals_np[gi, sl]), totals=tot,
                     per_component_dev=per[gi],
                     n_candidates=len(req.candidate_list),
-                    n_components=req.n_components)
+                    n_components=req.n_components, rid=req.rid)
         if groups:
             self.breaker.record(dispatch_ok)
         self.decisions += len(requests)
         if requests:
-            share = (time.time() - t_start) / len(requests)
+            share = (time.perf_counter() - t_start) / len(requests)
             for r in results:
                 r.service_seconds = share
             if obs.enabled():

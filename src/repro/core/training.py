@@ -29,8 +29,8 @@ import numpy as np
 
 from repro import obs
 from repro.core import model as enel_model
-from repro.core.graph import (ComponentGraph, TrainingCache, pow2_bucket,
-                              stack_graphs)
+from repro.core.graph import (ComponentGraph, TrainingCache, copy_tree,
+                              pow2_bucket, stack_graphs)
 
 HUBER_DELTA = 10.0
 
@@ -189,6 +189,9 @@ class EnelTrainer:
         self.cache: Optional[TrainingCache] = None
         self.cache_capacity = cache_capacity
         self._fit_calls = 0
+        self._register_obs(obs_name)
+
+    def _register_obs(self, obs_name: Optional[str]) -> None:
         # non-finite guard telemetry (see _adam_update): registry-backed
         # behind the original attribute API (nonfinite_steps /
         # last_skipped_steps / poisoned_fits properties below)
@@ -212,6 +215,22 @@ class EnelTrainer:
         return obs.span("enel.fit", _ring=True, trainer=self.obs_name,
                         route=route, mode="scratch" if scratch else "tune",
                         steps=_round_steps(steps))
+
+    def copy(self, seed: int) -> "EnelTrainer":
+        """A trainer of its own with this one's learned state: parameters,
+        optimizer state and history ring copied into new device buffers
+        (one compiled call, nothing aliased), the cadence counters kept,
+        telemetry of its own; ``seed`` keys the copy's dropout and its
+        scratch retrains."""
+        out = EnelTrainer.__new__(EnelTrainer)
+        out.__dict__.update(self.__dict__)
+        out.seed = seed
+        out._register_obs(None)
+        out.params = copy_tree(self.params)
+        out.opt = copy_tree(self.opt)
+        if self.cache is not None:
+            out.cache = self.cache.copy()
+        return out
 
     def _reset_opt(self):
         zeros = jax.tree_util.tree_map(jnp.zeros_like, self.params)

@@ -12,6 +12,10 @@ ride one vectorized dispatch (``engine="batched"``) and same-bucket
 decisions from different jobs ride a single jit dispatch, while each job
 still sees its own model's predictions.
 
+:meth:`FleetCampaign.serve_arrivals` drives the same generators open-loop:
+each tenant reaches its decision points on its own schedule, requests queue
+on arrival and every dispatch takes whatever is queued.
+
 :meth:`FleetCampaign.arrival_campaign` adds the multi-tenant capacity model:
 a global executor pool with Poisson job arrivals — concurrent jobs contend,
 and every rescaling decision is capped to the job's fair share of the free
@@ -24,14 +28,17 @@ decision ``alloc_i + free // n_pending``.
 from __future__ import annotations
 
 import copy
+import math
 import pickle
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro import obs
-from repro.core.service import DecisionService, apply_capacity
+from repro.core.service import (DecisionRequest, DecisionService,
+                                apply_capacity)
 from repro.dataflow.runner import JobExperiment, RunStats
 from repro.dataflow.workloads import SCALEOUT_RANGE
 from repro.sim.engine import BatchedClusterSim, SimStepRequest
@@ -46,6 +53,30 @@ class CapacityTrace:
     pool_size: int
     capped_decisions: int = 0
     arrivals: int = 0
+
+
+@dataclass
+class Arrival:
+    """One scheduled decision arrival of :meth:`FleetCampaign.serve_arrivals`
+    and what became of it, on the serving loop's clock: ``due`` as
+    scheduled, ``taken`` when the ``decide()`` that took its request
+    started, ``applied`` when the send that applied its decision returned,
+    ``batch`` the requests that ``decide()`` took."""
+    due: float
+    tenant: int
+    taken: float = math.nan
+    applied: float = math.nan
+    batch: int = 0
+
+
+class WallClock:
+    """The serving loop's default clock: host time and a sleep on it."""
+    now = staticmethod(time.perf_counter)
+    sleep = staticmethod(time.sleep)
+
+
+# decide() batch sizes: the job-axis rungs and what lies between them
+_BATCH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0, 1024.0)
 
 
 @dataclass
@@ -202,6 +233,14 @@ class FleetCampaign:
                 exp.backend = shared
                 exp.sim_slot = shared.register(exp.job, exp.seed,
                                                exp.scenario)
+        # open-loop state (serve_arrivals): each tenant's running generator,
+        # its pending sim step or its held component result (one whose
+        # delivery asks for a decision), and its finished runs
+        self._open_gens: Dict[int, object] = {}
+        self._stepping: Dict[int, SimStepRequest] = {}
+        self._held: Dict[int, object] = {}
+        self.open_stats: Dict[int, List[RunStats]] = {
+            i: [] for i in range(len(self.experiments))}
 
     def profile(self, n_runs: int = 10) -> None:
         for exp in self.experiments:
@@ -242,8 +281,9 @@ class FleetCampaign:
             return self._round_body(gens, sims, decs, stats, caps,
                                     on_decision, on_result)
 
-    def _round_body(self, gens, sims, decs, stats, caps, on_decision,
-                    on_result):
+    def _step_sims(self, sims: Dict[int, SimStepRequest]
+                   ) -> Dict[int, object]:
+        """Run every pending sim step, one batched call per backend."""
         results: Dict[int, object] = {}
         by_backend: Dict[int, List[int]] = {}
         for i in sims:
@@ -255,6 +295,25 @@ class FleetCampaign:
                 out = backend.step([sims[i] for i in ids])
             for i, res in zip(ids, out):
                 results[i] = res
+        return results
+
+    @staticmethod
+    def _resume(gens: Dict[int, object], i: int, res,
+                stats: Dict[int, RunStats], on_result=None):
+        """Feed ``res`` to generator ``i``: returns its next request, or
+        ``None`` when its run ended (its RunStats then in ``stats[i]``)."""
+        if on_result is not None:
+            on_result(i, res)
+        try:
+            with obs.span("enel.resume"):
+                return gens[i].send(res)
+        except StopIteration as stop:
+            stats[i] = stop.value
+            return None
+
+    def _round_body(self, gens, sims, decs, stats, caps, on_decision,
+                    on_result):
+        results = self._step_sims(sims)
         capped = 0
         if decs:
             ids = list(decs)
@@ -273,14 +332,11 @@ class FleetCampaign:
         nxt: Dict[int, object] = {}
         done: List[int] = []
         for i, res in results.items():
-            if on_result is not None:
-                on_result(i, res)
-            try:
-                with obs.span("enel.resume"):
-                    nxt[i] = gens[i].send(res)
-            except StopIteration as stop:
-                stats[i] = stop.value
+            req = self._resume(gens, i, res, stats, on_result)
+            if req is None:
                 done.append(i)
+            else:
+                nxt[i] = req
         return nxt, capped, done
 
     def _drain(self, gens: Dict[int, object]) -> Dict[int, RunStats]:
@@ -494,6 +550,125 @@ class FleetCampaign:
                 latest[-1], stop_after_round=stop)
             latest.extend(ckpts)
         return stats, restores
+
+    # ---------------------------------------------------------- open loop
+    def serve_arrivals(self, schedule: Sequence[Tuple[float, int]], *,
+                       clock=None, start: Optional[float] = None
+                       ) -> List[Arrival]:
+        """Serve an open-loop schedule of decision arrivals.
+
+        ``schedule`` holds ``(time, tenant)`` pairs, times in seconds after
+        ``start`` (default: the clock's now).  Tenants advance on their own,
+        not in lockstep rounds: a tenant is ready once its next component
+        result is computed and its last decision applied, and its arrival
+        delivers that result to its generator, which prepares the request
+        (``prepare_request``) into the queue.  An arrival whose tenant is
+        not ready yet waits for it, late by the wait.  Whenever requests
+        are queued, one ``decide()`` takes them all, each result goes back
+        to its generator, and the generators' next sim steps are batched
+        per backend for every tenant waiting on one, between dispatches.  A
+        run that ends fits as the lockstep path does and the tenant's next
+        run starts.  ``clock`` has ``now()`` and ``sleep(seconds)``
+        (default :class:`WallClock`); the loop sleeps only until the next
+        arrival is due.  Returns when every arrival is applied and no
+        tenant waits on a sim step; each :class:`Arrival` records when it
+        was taken and applied, so lateness counts from the schedule.
+        Finished runs go to ``open_stats``.  Runs are Enel's, without
+        injected failures.
+        """
+        clock = clock or WallClock
+        t0 = clock.now() if start is None else start
+        arrivals = sorted((Arrival(t0 + float(t), int(i))
+                           for t, i in schedule), key=lambda a: a.due)
+        gens = self._open_gens
+        if not gens:                          # first call: start every run
+            for i, exp in enumerate(self.experiments):
+                gens[i] = exp.adaptive_run_gen("enel", False)
+            self._stepping.update(self._start(gens, {}))
+        reg = obs.registry()
+        depth = reg.gauge("enel_arrival_queue_depth",
+                          "arrivals due and not yet taken by a decide()"
+                          ).labels(service=self.service.obs_name)
+        reg.histogram("enel_queue_wait_seconds",
+                      "from an arrival's scheduled time to the start of "
+                      "the decide() that took its request")
+        reg.histogram("enel_decide_batch_requests",
+                      "requests one open-loop decide() took",
+                      buckets=_BATCH_BUCKETS)
+        nxt, due = 0, []
+        while True:
+            now = clock.now()
+            while nxt < len(arrivals) and arrivals[nxt].due <= now:
+                due.append(arrivals[nxt])
+                nxt += 1
+            ready = [a for a in due if a.tenant in self._held]
+            if not (ready or self._stepping):
+                if nxt < len(arrivals):
+                    clock.sleep(max(0.0, arrivals[nxt].due - now))
+                    continue
+                if due:
+                    raise RuntimeError(
+                        f"{len(due)} arrivals for tenants that never "
+                        "became ready")
+                return arrivals
+            depth.set(len(due))
+            with obs.span("enel.serve", _ring=True, queued=len(due)) as sp:
+                queue = []
+                for a in ready:
+                    if a.tenant not in self._held:  # twice due: next cycle
+                        continue
+                    req = self._resume(gens, a.tenant,
+                                       self._held.pop(a.tenant), {})
+                    assert isinstance(req, DecisionRequest), \
+                        "an arrival delivers a result that asks to decide"
+                    queue.append((a, req))
+                taken = {id(a) for a, _ in queue}
+                due = [a for a in due if id(a) not in taken]
+                groups = self.service.dispatches
+                if queue:
+                    self._dispatch_queue(gens, queue, clock)
+                sims = len(self._stepping)
+                if sims:
+                    self._advance(self._step_sims(self._stepping))
+                sp.set(taken=len(queue), sims=sims,
+                       groups=self.service.dispatches - groups)
+
+    def _dispatch_queue(self, gens, queue, clock) -> None:
+        """One ``decide()`` over every queued request; each result is sent
+        back to its own generator, whose next sim step then waits."""
+        t = clock.now()
+        results = self.service.decide([req for _, req in queue])
+        obs.observe("enel_decide_batch_requests", float(len(queue)),
+                    service=self.service.obs_name)
+        for (a, _), res in zip(queue, results):
+            a.taken, a.batch = t, len(queue)
+            obs.observe("enel_queue_wait_seconds", t - a.due,
+                        service=self.service.obs_name)
+            step = self._resume(gens, a.tenant, res, {})
+            a.applied = clock.now()
+            self._stepping[a.tenant] = step
+
+    def _advance(self, results: Dict[int, object]) -> None:
+        """Route sim results: one whose delivery asks for a decision is held
+        for the tenant's next arrival; any other is delivered now, and a
+        run that ends starts the tenant's next."""
+        steps = self._stepping
+        self._stepping = {}
+        gens = self._open_gens
+        for i, res in results.items():
+            exp = self.experiments[i]
+            if exp.decides_after(steps[i].comp_idx):
+                self._held[i] = res
+                continue
+            stats: Dict[int, RunStats] = {}
+            req = self._resume(gens, i, res, stats)
+            if req is None:
+                self.open_stats[i].append(stats[i])
+                gens[i] = exp.adaptive_run_gen("enel", False)
+                req = self._start({i: gens[i]}, {})[i]
+            assert isinstance(req, SimStepRequest), \
+                "a decision asked for where none was expected"
+            self._stepping[i] = req
 
     # ------------------------------------------------------- fused campaigns
     def fused_campaign(self, n_runs: int, method: str = "enel",

@@ -237,6 +237,29 @@ class JobExperiment:
         self.stats: List[RunStats] = []
         self._run_idx = 0
 
+    def decides_after(self, comp_idx: int) -> bool:
+        """Whether an adaptive run asks for a decision once component
+        ``comp_idx`` has run."""
+        return comp_idx < self.job.n_components - 1 and \
+            comp_idx % self.decision_interval == 0
+
+    def adopt_profile(self, src: "JobExperiment") -> None:
+        """Make ``src``'s profiled state this tenant's own: its target,
+        history and learned models, copied into this tenant's own trainer
+        (new device buffers, no buffer aliased; this tenant's seed keys its
+        dropout and scratch retrains), scaler and Ellis model.  The context
+        encoder, which describes the job and its dataset, is shared.  The
+        sim slot stays this tenant's own.  Profiling one tenant per class
+        and adopting it costs a fraction of profiling every tenant."""
+        self.encoder = src.encoder
+        self.trainer = src.trainer.copy(self.seed)
+        self.enel = src.enel.copy(self.trainer)
+        self.ellis = copy.deepcopy(src.ellis)
+        self.target = src.target
+        self._run_idx = src._run_idx
+        self.stats = copy.deepcopy(src.stats)
+        self.graph_history = list(src.graph_history)
+
     # ----------------------------------------------------------- checkpoint
     def snapshot_state(self) -> Dict:
         """Everything a trace-identical resume needs: learned state (model
@@ -344,8 +367,7 @@ class JobExperiment:
             prev_summary = summary_node(nodes, name=f"P{k}")
             s_prev = s
             # --- dynamic scaling decision at the component boundary
-            if scaler and k < job.n_components - 1 and \
-                    k % self.decision_interval == 0:
+            if scaler and self.decides_after(k):
                 # decision latency = this job's local work + its amortized
                 # share of the service dispatch (result.service_seconds);
                 # the suspended yield interval is NOT billed — under fleet

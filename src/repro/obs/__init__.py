@@ -10,9 +10,9 @@ its own clock next to the device ops; outside a profiler session the
 annotation records nothing.  With observability on, the span also observes
 its host wall time into ``enel_span_seconds{span=kind}``, and a span
 opened with ``_ring=True`` (one per layer call: ``enel.round``,
-``enel.decide``, ``enel.fit``, ``enel.sim_step``) is recorded in the
-flight recorder with its start, end and parent; events emitted while it
-is open take it as their ``parent``.  Phase and per-request spans stay
+``enel.serve``, ``enel.decide``, ``enel.fit``, ``enel.sim_step``) is
+recorded in the flight recorder with its start, end and parent; events
+emitted while it is open take it as their ``parent``.  Phase and per-request spans stay
 out of the ring so they cannot push causal events out of it.
 
 Contract: with observability disabled, decisions are bit-exact vs the

@@ -406,3 +406,30 @@ def test_group_stack_compiles_stay_bounded():
     assert seen                             # sizes 3 and 4 both stacked
     for name in ("group_stack", "fleet_sweep"):
         assert enel_model.TRACE_COUNTS[name] == before.get(name, 0), name
+
+
+def test_burst_splits_at_top_rung_without_new_trace(fleet_exps):
+    """A burst of 40 same-bucket requests is split into groups of at most
+    the top job rung (32 + 8), each answered by its own request's result,
+    and after a warm-up of every rung it traces no new shape (padding 40
+    to 64 would)."""
+    import dataclasses
+    exp = fleet_exps[0]
+    req = exp.enel.prepare_request(**_decision_kwargs(exp))
+    svc = DecisionService()
+    for j in service_mod.JOB_LADDER:                       # warm-up
+        svc.decide([dataclasses.replace(req) for _ in range(j)])
+    sizes = []
+    dispatch = svc._dispatch_group
+
+    def record(key, group):
+        sizes.append(len(group))
+        return dispatch(key, group)
+    svc._dispatch_group = record
+    burst = [dataclasses.replace(req, rid=1000 + k) for k in range(40)]
+    before = dict(enel_model.TRACE_COUNTS)
+    out = svc.decide(burst)
+    assert sorted(sizes) == [8, 32]
+    assert dict(enel_model.TRACE_COUNTS) == before
+    assert [r.rid for r in out] == [q.rid for q in burst]
+    assert len({(r.scaleout, tuple(r.totals.items())) for r in out}) == 1
