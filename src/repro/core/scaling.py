@@ -68,7 +68,9 @@ _request_ids = itertools.count()    # DecisionRequest.rid allocator
 
 
 class _TemplateDeviceCache:
-    """Device-resident sweep-template reuse ACROSS decision points.
+    """Device-resident sweep-template reuse ACROSS decision points of the
+    single-job :meth:`EnelScaler.recommend` (service requests hand their
+    template to the service on the host instead).
 
     The template base arrays (K, N, ...) are candidate-invariant and change
     little between decision points with the same remaining-component count
@@ -179,7 +181,8 @@ class EnelScaler:
         # (held as a DecisionResult — device-resident, transferred lazily)
         self.last_candidates: List[int] = []
         self._last_result: Optional[DecisionResult] = None
-        # device-resident template arrays reused across decision points
+        # device-resident template arrays reused across decision points of
+        # the single-job recommend() (service requests carry host templates)
         self.template_cache = _TemplateDeviceCache()
         # guardrail backstop for the single-job recommend() path (the fleet
         # service carries its own policy): non-finite sweep totals never
@@ -395,10 +398,10 @@ class EnelScaler:
 
         The sweep is assembled exactly as :meth:`recommend` would, then
         padded to the fixed shape ladders (padded components read out as
-        exactly 0 and padded candidates are masked from the pick), the real
-        edges are gathered for the sparse engine, and the template base
-        arrays are swapped for the device-resident cache copies.  Returns
-        ``None`` when there is nothing left to decide.
+        exactly 0 and padded candidates are masked from the pick) and the
+        real edges are gathered for the sparse engine.  The template stays
+        in host arrays: the service uploads it once per decision group.
+        Returns ``None`` when there is nothing left to decide.
         """
         if next_comp >= n_components:
             return None
@@ -424,8 +427,6 @@ class EnelScaler:
                 n_components=n_components,
                 current_scaleout=current_scaleout, candidates=candidates,
                 current_summary=current_summary)
-        cache = self.template_cache
-        moved0 = (cache.transfers, cache.skips)
         with obs.span("enel.prep.adopt") as sp:
             template, deltas, (c_real, k_real) = bucket_sweep(template,
                                                               deltas)
@@ -445,9 +446,10 @@ class EnelScaler:
                 self._edge_cache[ekey] = (template.base["adj"].copy(),
                                           template.base["mask"].copy(), edges)
                 edge_dst, edge_src, edge_valid = edges
-            template = cache.adopt(template, c_b)
-            sp.set(transfers=cache.transfers - moved0[0],
-                   skips=cache.skips - moved0[1])
+            # the template stays on the host: the service uploads it once
+            # per decision group with the rest of the group's host leaves
+            sp.set(host_bytes=template.h_onehot.nbytes + sum(
+                v.nbytes for v in template.base.values()))
         ckey = (c_b,) + tuple(candidates)
         if ckey in self._cand_cache:
             cand_arr, cand_valid = self._cand_cache[ckey]

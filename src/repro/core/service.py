@@ -137,9 +137,10 @@ def _stack_rows(treedef, leaf_rows, tally):
 class DecisionRequest:
     """One job's pending rescaling decision, already shape-bucketed.
 
-    ``base``/``h_onehot`` may be device arrays (the scaler's template cache
-    keeps them resident across decision points); ``deltas`` and the edge
-    lists are fresh host arrays every decision.
+    ``base``, ``h_onehot`` and ``deltas`` are fresh host arrays every
+    decision, uploaded once per decision group; the edge lists and the
+    candidate arrays are object-stable while their values are unchanged,
+    so the stack memo can reuse their stacks.
 
     ``current_scaleout`` carries the requester's live allocation so a
     fallback answer can step FROM somewhere; ``best_effort`` marks requests
@@ -422,9 +423,9 @@ class DecisionService:
         self.shed_capacity = shed_capacity
         self.fault_injector = None      # chaos hook (see repro.sim.chaos)
         self._rng = np.random.RandomState(seed ^ 0xbac0ff)  # backoff jitter
-        # identity-memoized stacks: params / template-base device arrays /
-        # edge lists are object-stable across decision rounds (the scalers'
-        # caches re-serve the same ndarrays while values are unchanged), so
+        # identity-memoized stacks: params / edge lists / candidate arrays
+        # are object-stable across decision rounds (the scalers' caches
+        # re-serve the same ndarrays while values are unchanged), so
         # their (J, ...) stacks are reused instead of re-stacked per round.
         # LRU-bounded so a long campaign over many bucket/fleet shapes
         # cannot pin stacked device arrays without limit.

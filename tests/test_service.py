@@ -8,11 +8,14 @@ The contracts under test:
 * one batched service dispatch over a multi-job fleet returns exactly the
   decisions the jobs would get from sequential per-job ``recommend``;
 * the template device cache is a bounded LRU;
+* requests hand their template to the service as host arrays, decide
+  exactly as with a device-resident template, and upload nothing in prep;
 * a group's request stack is one compiled call per memo field, equal to
   the per-leaf stack bit for bit and compiled once per job rung;
 * the on-device pick replicates the host pick's tie-breaking.
 """
 import copy
+import dataclasses
 import types
 
 import jax
@@ -261,6 +264,98 @@ def test_double_buffered_dispatch_matches_sync(fleet_exps):
         np.testing.assert_array_equal(a.per_component, b.per_component)
 
 
+# ------------------------------------------------ host templates in requests
+@pytest.mark.parametrize("double_buffer", [False, True])
+@pytest.mark.parametrize("width", [1, 3])
+def test_host_template_matches_device_template(fleet_exps, width,
+                                               double_buffer):
+    """Requests hand ``base``/``h_onehot`` to the service as host arrays;
+    a group of them decides exactly as the same requests with the template
+    adopted into a device cache (the service then stacks device leaves)."""
+    exp = fleet_exps[0]
+    kw = _decision_kwargs(exp)
+    exp.enel.prepare_request(**kw)              # warm probe caches
+    reqs = []
+    for i in range(width):
+        exp.encoder.rng = np.random.RandomState(3000 + i)
+        reqs.append(exp.enel.prepare_request(
+            **dict(kw, elapsed=kw["elapsed"] + i)))
+    cache = _TemplateDeviceCache()
+    dev_reqs = []
+    for req in reqs:
+        assert isinstance(req.h_onehot, np.ndarray)
+        assert all(isinstance(v, np.ndarray) for v in req.base.values())
+        flags = np.zeros(req.h_onehot.shape, bool)
+        adopted = cache.adopt(SweepTemplate(
+            base=req.base, h_onehot=req.h_onehot, a_follows_a=flags,
+            a_follows_z=flags, z_follows_a=flags, z_follows_z=flags,
+            r_eq=req.base["r"], r_neq=req.base["r"]), len(req.candidates))
+        assert isinstance(adopted.h_onehot, jax.Array)
+        dev_reqs.append(dataclasses.replace(
+            req, base=adopted.base, h_onehot=adopted.h_onehot))
+    host_svc = DecisionService(double_buffer=double_buffer)
+    dev_svc = DecisionService(double_buffer=double_buffer)
+    res_h = host_svc.decide(reqs)
+    res_d = dev_svc.decide(dev_reqs)
+    assert host_svc.dispatches == dev_svc.dispatches == 1
+    for a, b in zip(res_h, res_d):
+        assert not a.fallback and not b.fallback
+        assert a.scaleout == b.scaleout
+        assert a.predicted == b.predicted
+        assert a.totals == b.totals
+
+
+def test_lockstep_round_uploads_templates_in_decide(monkeypatch):
+    """One adaptive lockstep round through the service: no tenant's device
+    template cache uploads anything, each prep span reports the template
+    bytes it hands over, and the service stacks those host arrays itself."""
+    from repro import obs
+    exps = [JobExperiment(k, seed=80 + i, candidate_stride=4)
+            for i, k in enumerate(("lr", "lr", "kmeans"))]
+    camp = FleetCampaign(exps)
+    camp.profile(2)
+    spans, stacked, reqs = [], [], []
+    real_span, real_stack = obs.span, service_mod._stack_rows
+
+    def recording_span(kind, _ring=False, **attrs):
+        sp = real_span(kind, _ring, **attrs)
+        spans.append(sp)
+        return sp
+
+    def recording_stack(treedef, leaf_rows, tally):
+        stacked.extend(leaf for row in leaf_rows for leaf in row
+                       if isinstance(leaf, np.ndarray))
+        return real_stack(treedef, leaf_rows, tally)
+
+    def recording_prepare(prepare):
+        def wrapped(**kw):
+            req = prepare(**kw)
+            reqs.append(req)
+            return req
+        return wrapped
+
+    monkeypatch.setattr(obs, "span", recording_span)
+    monkeypatch.setattr(service_mod, "_stack_rows", recording_stack)
+    for exp in exps:
+        exp.enel.prepare_request = recording_prepare(
+            exp.enel.prepare_request)
+    stats = camp.adaptive_round("enel", inject_failures=False)
+    assert reqs and all(st.cache_transfers == 0 for st in stats)
+    assert all(exp.enel.template_cache.transfers == 0 for exp in exps)
+    adopt = [sp.attrs["host_bytes"] for sp in spans
+             if sp.kind == "enel.prep.adopt"]
+    assert len(adopt) == len(reqs) and min(adopt) > 0
+    assert adopt == [req.h_onehot.nbytes + sum(
+        v.nbytes for v in req.base.values()) for req in reqs]
+    stack_bytes = sum(sp.attrs["bytes"] for sp in spans
+                      if sp.kind == "enel.decide.stack")
+    assert stack_bytes >= sum(adopt)
+    uploaded = {id(leaf) for leaf in stacked}
+    for req in reqs:
+        assert id(req.h_onehot) in uploaded
+        assert all(id(v) in uploaded for v in req.base.values())
+
+
 # ----------------------------------------------- cross-engine runner parity
 def test_runner_parity_numpy_vs_batched_engine():
     """Same seed -> identical RunRecords and decisions through the FULL
@@ -413,7 +508,6 @@ def test_burst_splits_at_top_rung_without_new_trace(fleet_exps):
     the top job rung (32 + 8), each answered by its own request's result,
     and after a warm-up of every rung it traces no new shape (padding 40
     to 64 would)."""
-    import dataclasses
     exp = fleet_exps[0]
     req = exp.enel.prepare_request(**_decision_kwargs(exp))
     svc = DecisionService()
