@@ -2,7 +2,8 @@
 job class (GBT decomposes into more components -> more graphs -> longer),
 plus the scale-out *decision* latency: the per-candidate graph-construction
 path (``EnelScaler.recommend_pergraph``) vs. the batched template+delta
-sweep (``EnelScaler.recommend``), plus the *fit* latency: the legacy
+sweep that ``EnelScaler.recommend`` answers through a one-request
+``DecisionService.decide``, plus the *fit* latency: the legacy
 restack-per-call path (``EnelTrainer.fit``) vs. the device-resident
 ring-buffer fast path (``EnelTrainer.fit_resident``) the runner now uses.
 Emits ``BENCH_decision.json`` so the decision- and fit-latency trajectories
@@ -128,17 +129,17 @@ def measure_decision(job_key: str, seed: int = 0, repeats: int = 5) -> Dict:
 
     Reproduces the runner's mid-run decision context (component 0 finished,
     all others remaining — the largest sweep of the job) and times both
-    engines after jit warmup.  Also records the worst per-component deviation
-    between the batched sweep and per-graph predictions of the SAME
+    paths after jit warmup.  Also records the worst per-component deviation
+    between the service's sweep and per-graph predictions of the SAME
     template-derived graphs (materialized host-side per candidate).
     """
     from repro.core import model as enel_model
     from repro.core.graph import materialize_candidate, summary_node
+    from repro.core.service import DecisionService
     from repro.dataflow.runner import (_component_nodes, _future_nodes,
                                        _to_graph)
 
-    traces0 = (enel_model.trace_count("sweep_per_component") +
-               enel_model.trace_count("fleet_sweep"))
+    traces0 = enel_model.trace_count("fleet_sweep")
     exp = JobExperiment(job_key, seed=seed)
     exp.profile(4)
     job = exp.job
@@ -153,18 +154,17 @@ def measure_decision(job_key: str, seed: int = 0, repeats: int = 5) -> Dict:
               current_scaleout=8, target_runtime=exp.target,
               current_summary=summ)
 
-    # numerical parity of the batching itself: batched sweep vs per-graph
-    # predict on IDENTICAL template-materialized graphs (isolates the jit
-    # batching; context-freezing semantics are shared by both sides here)
+    # numerical parity of the batching itself: the service's sweep vs
+    # per-graph predict on IDENTICAL template-materialized graphs (isolates
+    # the jit batching; context-freezing semantics are shared by both sides
+    # here).  A request carries its padded template's base and h_onehot.
     cands = exp.enel.candidate_scaleouts(8)
-    template, deltas = exp.enel.build_sweep(
-        graph_builder=builder, next_comp=1, n_components=job.n_components,
-        current_scaleout=8, candidates=cands, current_summary=summ)
-    per = exp.enel.trainer.predict_sweep(template, deltas)
+    req = exp.enel.prepare_request(**kw)
+    per = DecisionService().decide([req])[0].per_component
     max_dev = 0.0
     for c in range(len(cands)):
         ref = exp.enel.trainer.predict_stacked(
-            materialize_candidate(template, deltas, c))
+            materialize_candidate(req, req.deltas, c))[:req.n_components]
         max_dev = max(max_dev, float(np.abs(ref - per[c]).max()))
 
     # end-to-end divergence vs the legacy engine (includes the deliberate
@@ -198,7 +198,6 @@ def measure_decision(job_key: str, seed: int = 0, repeats: int = 5) -> Dict:
             # sweep-jit compiles this job's decision context cost (warmup
             # included) — the compile-amortization axis of the perf story
             "decide_recompiles":
-                enel_model.trace_count("sweep_per_component") +
                 enel_model.trace_count("fleet_sweep") - traces0}
 
 

@@ -5,10 +5,11 @@ Three measurements:
 
 * **Throughput** — a fleet of concurrent jobs (all four job classes x seeds,
   cycling) each needs a mid-run rescaling decision.  ``sequential`` answers
-  them one ``EnelScaler.recommend`` at a time (the dense per-job engine);
-  ``batched`` prepares shape-bucketed requests and answers all of them in
-  one ``DecisionService.decide`` call (sparse engine, one jit dispatch per
-  bucket, one transfer per group).  Reported at fleet sizes 1/8/32.
+  them one ``EnelScaler.recommend`` at a time (a one-request decide on
+  each job's own service); ``batched`` prepares shape-bucketed requests and
+  answers all of them in one ``DecisionService.decide`` call (one jit
+  dispatch per bucket, one transfer per group).  Reported at fleet sizes
+  1/8/32.
 
 * **Compile budget** — a full 4-job mini-campaign (profiling + adaptive runs
   covering every remaining-component count) must compile the fleet sweep at

@@ -15,10 +15,8 @@ against its plain reference:
    one scratch retrain through the donated fit jit), with zero guardrail
    trips, fallbacks, retries or dispatch failures, then decisions of the
    trained models against the per-graph reference
-   ``EnelScaler.recommend_pergraph`` (and the dense sweep ``recommend``);
-3. graph-prop kernel: the forward and custom-VJP backward Pallas kernels,
-   compiled for the chip, at B=1152 and N in {8, 16}, against
-   ``kernels/graph_prop/ref.py`` and ``jax.grad`` of its jnp mirror.
+   ``EnelScaler.recommend_pergraph``, both batched in one service call and
+   one job at a time through ``EnelScaler.recommend``.
 
 It prints each phase's wall time (first calls include compilation; no
 timing here is a benchmark number), decision counts, the max deviations and
@@ -52,13 +50,11 @@ FUSED_TENANTS = 1024         # a multiple of the 4 job classes
 FUSED_RUNS = 2
 LIVE_TENANTS = 32
 RETRAIN_EVERY = 5            # the runner's scratch-retrain cadence = live runs
-KERNEL_BATCH = 1152          # 36 candidates x 32 components
 
 # stated tolerances
 FUSED_STEPPED_TOL = 1e-5     # fused vs stepped float leaves (ints exact)
 SIM_RTOL = 1e-4              # chip stage runtimes vs numpy ClusterSim
 DECISION_TOL = 1e-2          # predicted totals vs per-graph, x job target
-KERNEL_TOL = 1e-4            # kernel vs ref, max |dev| / max |ref|
 
 
 class Check:
@@ -202,7 +198,7 @@ def _frozen_builder(exp, s_now: int):
     """The runner's future-component graph builder with every node context
     taken at the current scale-out and no software-version dropout: the
     candidate-invariant contexts the batched sweep assumes, so the per-graph
-    reference and the sweep engines see the same graphs."""
+    reference and the sweep engine see the same graphs."""
     from repro.core.graph import NodeAttrs
     from repro.dataflow.runner import _to_graph
     job, enc = exp.job, exp.encoder
@@ -279,101 +275,39 @@ def phase_live(size: int, n_runs: int, check: Check) -> dict:
     check(all(e.trainer.params_finite() for e in exps),
           "live: every model's parameters finite after training")
 
-    # trained models vs the plain per-graph reference (and the dense sweep)
+    # trained models vs the plain per-graph reference, batched in one
+    # service call and one job at a time through recommend()
     t0 = time.time()
     points = [(exp, _decision_point(exp, s)) for exp in exps[:len(JOB_CYCLE)]
               for s in (8, 24)]
     results = svc.decide([exp.enel.prepare_request(**kw)
                           for exp, kw in points])
-    dev_svc = dev_dense = 0.0
+    dev_svc = dev_one = 0.0
     agree = 0
     for (exp, kw), res in zip(points, results):
         s_ref, _, tot_ref = exp.enel.recommend_pergraph(**kw)
-        s_dense, _, tot_dense = exp.enel.recommend(**kw)
+        s_one, _, tot_one = exp.enel.recommend(**kw)
         tol = DECISION_TOL * exp.target
         dev_svc = max(dev_svc, max(abs(res.totals[c] - tot_ref[c])
                                    for c in tot_ref) / exp.target)
-        dev_dense = max(dev_dense, max(abs(tot_dense[c] - tot_ref[c])
-                                       for c in tot_ref) / exp.target)
+        dev_one = max(dev_one, max(abs(tot_one[c] - tot_ref[c])
+                                   for c in tot_ref) / exp.target)
         agree += int(res.scaleout == s_ref)
         check(not res.fallback and
               pick_consistent(res.scaleout, tot_ref, exp.target, tol) and
-              pick_consistent(s_dense, tot_ref, exp.target, tol),
+              pick_consistent(s_one, tot_ref, exp.target, tol),
               f"live {exp.job.name} s={kw['current_scaleout']}: service "
-              f"pick {res.scaleout}, dense {s_dense}, per-graph {s_ref}")
-    check(dev_svc <= DECISION_TOL and dev_dense <= DECISION_TOL,
+              f"pick {res.scaleout}, recommend {s_one}, per-graph {s_ref}")
+    check(dev_svc <= DECISION_TOL and dev_one <= DECISION_TOL,
           f"live: totals vs per-graph, max dev / target: service "
-          f"{dev_svc:.3g}, dense {dev_dense:.3g} <= {DECISION_TOL}")
+          f"{dev_svc:.3g}, recommend {dev_one:.3g} <= {DECISION_TOL}")
     return {"tenants": size, "runs": n_runs, "decisions": decisions,
             "dispatches": int(health["dispatches"]),
             "setup_s": setup_s, "campaign_s": campaign_s,
             "reference_s": time.time() - t0,
             "reference_points": len(points), "same_pick_as_pergraph": agree,
             "service_max_dev_over_target": float(dev_svc),
-            "dense_max_dev_over_target": float(dev_dense)}
-
-
-# ------------------------------------------------------- graph-prop kernel
-def phase_kernel(batch: int, nodes, check: Check, levels: int = 8,
-                 interpret: bool = False) -> dict:
-    import jax
-    import jax.numpy as jnp
-    from repro.core import model as enel_model
-    from repro.core.graph import N_METRICS
-    from repro.kernels.graph_prop.ops import graph_prop
-    from repro.kernels.graph_prop.ref import graph_prop_ref, graph_prop_ref_jnp
-
-    params = enel_model.init_enel(jax.random.PRNGKey(0))
-    np_params = jax.tree_util.tree_map(np.asarray, params)
-    out = {}
-    for n in nodes:
-        rng = np.random.RandomState(n)
-        x = rng.randn(batch, n, enel_model.X_DIM).astype(np.float32)
-        adj = np.tril(rng.rand(batch, n, n) < 0.3, -1)
-        valid = rng.rand(batch, n) < 0.5
-        m = rng.rand(batch, n, N_METRICS).astype(np.float32)
-        ce = rng.randn(batch, n, n).astype(np.float32)
-        cm = rng.randn(batch, n, N_METRICS).astype(np.float32)
-        adj_d, valid_d, x_d, m_d = map(jnp.asarray, (adj, valid, x, m))
-
-        def kern(p, xx, mm):
-            return graph_prop(p, xx, adj_d, mm, valid_d, levels=levels,
-                              interpret=interpret)
-
-        def ref(p, xx, mm):
-            return graph_prop_ref_jnp(p, xx, adj_d, mm, valid_d,
-                                      levels=levels)
-
-        def scalar(fn):
-            def f(p, xx, mm):
-                e, mh = fn(p, xx, mm)
-                return jnp.sum(e * ce) + jnp.sum(mh * cm)
-            return jax.jit(jax.grad(f, argnums=(0, 1, 2)))
-
-        t0 = time.time()
-        e, mh = jax.block_until_ready(jax.jit(kern)(params, x_d, m_d))
-        fwd_s = time.time() - t0
-        er, mr = graph_prop_ref(np_params, x, adj, m, valid, levels=levels)
-        fwd_dev = max(_max_rel(e, er), _max_rel(mh, mr))
-        t0 = time.time()
-        gk = jax.block_until_ready(scalar(kern)(params, x_d, m_d))
-        bwd_s = time.time() - t0
-        with jax.default_matmul_precision("highest"):
-            gr = scalar(ref)(params, x_d, m_d)
-        bwd_dev = max(_max_rel(a, b) for a, b in
-                      zip(jax.tree_util.tree_leaves(gk),
-                          jax.tree_util.tree_leaves(gr)))
-        check(fwd_dev <= KERNEL_TOL,
-              f"kernel B={batch} N={n}: forward vs ref.py, max dev "
-              f"{fwd_dev:.3g} <= {KERNEL_TOL}")
-        check(bwd_dev <= KERNEL_TOL,
-              f"kernel B={batch} N={n}: custom-VJP grads vs jax.grad(ref), "
-              f"max dev {bwd_dev:.3g} <= {KERNEL_TOL}")
-        out[f"N{n}"] = {"forward_first_call_s": fwd_s,
-                        "backward_first_call_s": bwd_s,
-                        "forward_max_dev": fwd_dev,
-                        "backward_max_dev": bwd_dev}
-    return out
+            "recommend_max_dev_over_target": float(dev_one)}
 
 
 # --------------------------------------------------------------------- main
@@ -398,8 +332,7 @@ def main() -> int:
     report = {"device": device}
     for name, run in (
             ("fused", lambda: phase_fused(FUSED_TENANTS, FUSED_RUNS, check)),
-            ("live", lambda: phase_live(LIVE_TENANTS, RETRAIN_EVERY, check)),
-            ("kernel", lambda: phase_kernel(KERNEL_BATCH, (8, 16), check))):
+            ("live", lambda: phase_live(LIVE_TENANTS, RETRAIN_EVERY, check))):
         print(f"phase {name}", flush=True)
         t0 = time.time()
         report[name] = run()
@@ -407,7 +340,7 @@ def main() -> int:
     stats = devs[0].memory_stats() or {}
     report["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
     report["failed"] = check.failed
-    for name in ("fused", "live", "kernel"):
+    for name in ("fused", "live"):
         print(f"{name}: {json.dumps(report[name], sort_keys=True)}")
     print(f"peak_bytes_in_use: {report['peak_bytes_in_use']}")
     os.makedirs(OUT_DIR, exist_ok=True)

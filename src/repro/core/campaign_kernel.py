@@ -71,8 +71,7 @@ from repro.core.graph import (CAND_LADDER, COMP_LADDER, EDGE_LADDER,
                               LEVEL_LADDER, N_METRICS, CTX_DIM, ladder_bucket,
                               historical_summaries_batch, pow2_bucket,
                               propagation_depth, ring_append)
-from repro.core.model import (graph_prop_kernel_enabled, init_enel,
-                              record_trace)
+from repro.core.model import init_enel, record_trace
 from repro.core.service import sweep_eval_one
 from repro.core.training import _adam_run_resident_impl, _round_steps
 
@@ -100,7 +99,6 @@ class PlanStatic(NamedTuple):
     scratch_steps: int   # _round_steps(steps)
     tune_steps: int      # _round_steps(fine_tune_steps)
     retrain_every: int
-    use_kernel: bool     # graph_prop Pallas kernel toggle (frozen at plan)
     levels: int          # bucketed propagation depth for the sweep
     telemetry: bool = False  # in-scan obs block (ENEL_OBS; default at
     #                          build_plan). False compiles the exact
@@ -345,8 +343,7 @@ def _step(st: PlanStatic, dev, carry, t):
 
         def one(pj, oj, bj, wj, kj, lr_j):
             return _adam_run_resident_impl(
-                pj, oj, bj, wj, kj, lr_j, dev["dropout_p"], steps,
-                st.use_kernel)
+                pj, oj, bj, wj, kj, lr_j, dev["dropout_p"], steps)
 
         return jax.vmap(one)(p, o, batch, w, keys, dev["lr"])
 
@@ -951,8 +948,7 @@ def build_plan(experiments, n_runs: int, *, inject_failures: bool = False,
         c_max=c_max, s_max=s_max, lo=lo, tune_rows=pow2_bucket(c_max),
         scratch_steps=_round_steps(steps),
         tune_steps=_round_steps(fine_tune_steps),
-        retrain_every=retrain_every,
-        use_kernel=graph_prop_kernel_enabled(), levels=levels,
+        retrain_every=retrain_every, levels=levels,
         telemetry=bool(telemetry))
     host = {
         "predicted": predicted, "targets": target.copy(),

@@ -159,7 +159,7 @@ def ladder_bucket(n: int, ladder: Sequence[int]) -> int:
 # only (a_raw, z_raw, r, summary-node attributes) change, so a decision point
 # is ONE candidate-invariant template per remaining component plus small
 # per-candidate delta arrays, evaluated in a single jit (see core/scaling.py
-# and model.sweep_per_component).
+# and service.sweep_eval_one).
 
 SWEEP_KEYS = ("context", "metrics", "metrics_valid", "a_raw", "z_raw", "r",
               "adj", "mask", "is_summary")

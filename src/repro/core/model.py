@@ -14,26 +14,22 @@ predictions flow to nodes whose real metrics are unobserved (future
 iterations), exactly the paper's online-inference mode.  ~5k parameters —
 "allows for training even using a CPU" (§IV-C).
 
-Two inference entry points share the math:
+Three engines share the math:
 
-* ``forward`` / ``forward_batch`` — the original per-graph path.
-* ``forward_stacked`` — batched inference over stacked (B, N, ...) arrays.
-  With the graph-prop kernel flag enabled (``ENEL_GRAPH_PROP_KERNEL=1`` or
-  :func:`set_graph_prop_kernel`), eqs. 6-7 run as one fused Pallas kernel
-  (``repro.kernels.graph_prop``); otherwise it is ``vmap(forward)``.  Both
-  routes are differentiable — the kernel carries a custom VJP backed by a
-  backward Pallas kernel — so training (``enel_loss``) goes through
-  ``forward_stacked`` and honours the same flag.
-* ``sweep_per_component`` — the batched candidate-sweep decision path: one
-  candidate-invariant template + per-candidate deltas, assembled and
-  evaluated inside a single jit (used by ``EnelScaler.recommend``).
+* ``forward`` / ``forward_batch`` — the per-graph path.
+* ``forward_stacked`` — ``vmap(forward)`` over stacked (B, N, ...) arrays at
+  a given propagation depth: training (``enel_loss``) differentiates
+  through it, and it is the plain reference the decision engine is tested
+  against.
+* ``sweep_sparse_totals`` — the decision engine: eqs. 3-7 on each graph's
+  real edges only, evaluated for every (candidate x component) graph of a
+  sweep by :func:`repro.core.service.sweep_eval_one` (the fleet service
+  and the fused campaign scan both call it).
 """
 from __future__ import annotations
 
-import functools
-import os
 from collections import Counter
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,22 +40,6 @@ HIDDEN = 32
 EDGE_DIM = 16
 X_DIM = 3 + CTX_DIM + 3          # a_vec ‖ c ‖ z_vec
 MAX_LEVELS = 8                   # longest DAG chain the propagation supports
-
-# --------------------------------------------------------------- kernel flag
-_USE_GRAPH_PROP_KERNEL = os.environ.get(
-    "ENEL_GRAPH_PROP_KERNEL", "0").lower() in ("1", "true", "yes")
-
-
-def set_graph_prop_kernel(enabled: bool) -> None:
-    """Route batched inference (forward_stacked / sweep) through the fused
-    Pallas graph-propagation kernel instead of inline jnp."""
-    global _USE_GRAPH_PROP_KERNEL
-    _USE_GRAPH_PROP_KERNEL = bool(enabled)
-
-
-def graph_prop_kernel_enabled(override: Optional[bool] = None) -> bool:
-    return _USE_GRAPH_PROP_KERNEL if override is None else bool(override)
-
 
 # -------------------------------------------------------------- trace counter
 # Every (re)compilation of a counted jit traces its Python body once, so a
@@ -242,35 +222,21 @@ forward_batch = jax.vmap(forward, in_axes=(None, 0))
 
 
 def forward_stacked(params: Dict, batch: Dict,
-                    use_kernel: Optional[bool] = None,
                     levels: int = MAX_LEVELS) -> Dict[str, jax.Array]:
-    """Batched inference over stacked (B, N, ...) graph arrays.
-
-    Dispatches eqs. 6-7 to the fused Pallas ``graph_prop`` kernel when the
-    flag is on (resolved at trace time — callers that jit must pass the
-    resolved flag as a static argument), else falls back to vmap(forward).
-    """
-    if not graph_prop_kernel_enabled(use_kernel):
-        if levels == MAX_LEVELS:
-            return forward_batch(params, batch)
-        return jax.vmap(lambda p, g: forward(p, g, levels),
-                        in_axes=(None, 0))(params, batch)
-    from repro.kernels.graph_prop.ops import graph_prop
-    a_vec, z_vec, x, adj = _prelude(batch)
-    e, m_hat = graph_prop(params, x, adj, batch["metrics"],
-                          batch["metrics_valid"], levels=levels)
-    return jax.vmap(functools.partial(_readout, levels=levels),
-                    in_axes=(None, 0, 0, 0, 0, 0, 0))(
-        params, batch, a_vec, z_vec, adj, e, m_hat)
+    """Batched inference over stacked (B, N, ...) graph arrays:
+    ``vmap(forward)`` at ``levels`` propagation rounds."""
+    if levels == MAX_LEVELS:
+        return forward_batch(params, batch)
+    return jax.vmap(lambda p, g: forward(p, g, levels),
+                    in_axes=(None, 0))(params, batch)
 
 
-def predict_total_runtime(params: Dict, graphs: Dict,
-                          use_kernel: Optional[bool] = None) -> jax.Array:
+def predict_total_runtime(params: Dict, graphs: Dict) -> jax.Array:
     """Total predicted runtime per component graph in a stacked batch."""
-    return forward_stacked(params, graphs, use_kernel)["total_runtime"]
+    return forward_stacked(params, graphs)["total_runtime"]
 
 
-# ------------------------------------------------------- candidate sweep jit
+# ---------------------------------------------------- candidate sweep batch
 def assemble_sweep_batch(base, h_onehot, deltas) -> Dict[str, jax.Array]:
     """Template + per-candidate deltas -> flat stacked (C*K, N, ...) batch.
 
@@ -301,36 +267,12 @@ def assemble_sweep_batch(base, h_onehot, deltas) -> Dict[str, jax.Array]:
     return {key: v.reshape((c * k,) + v.shape[2:]) for key, v in batch.items()}
 
 
-def _sweep_impl(params, base, h_onehot, deltas, use_kernel, levels):
-    """Assemble all (candidate x component) graphs from template + deltas on
-    device and evaluate them in one fused batch -> per-component totals
-    (C, K)."""
-    record_trace("sweep_per_component")
-    c, k = deltas["a_raw"].shape[:2]
-    flat = assemble_sweep_batch(base, h_onehot, deltas)
-    total = forward_stacked(params, flat, use_kernel=use_kernel, levels=levels)
-    return total["total_runtime"].reshape(c, k)
-
-
-# deltas are uploaded fresh for every decision, so their buffers are donated
-_sweep_jit = jax.jit(_sweep_impl, static_argnums=(4, 5), donate_argnums=(3,))
-
-
-def sweep_per_component(params: Dict, base: Dict, h_onehot, deltas,
-                        use_kernel: Optional[bool] = None,
-                        levels: int = MAX_LEVELS) -> jax.Array:
-    """Jitted batched candidate sweep -> per-component totals (C, K).
-    ``deltas`` is donated: pass device arrays nobody else holds."""
-    return _sweep_jit(params, base, h_onehot, deltas,
-                      graph_prop_kernel_enabled(use_kernel), levels)
-
-
 # ---------------------------------------------------- sparse-edge sweep engine
 # The component DAGs are near-chains: a graph holds at most a handful of real
-# edges, yet the dense engine evaluates f3/f4 on all N x N node pairs and
-# masks the rest away.  The fleet decision service instead gathers the few
-# real (dst, src) pairs into padded (B, E) edge lists and runs eqs. 6-7 with
-# segment reductions — identical math on the real edges (the dense path's
+# edges, yet ``forward`` evaluates f3/f4 on all N x N node pairs and masks
+# the rest away.  The decision engine instead gathers the few real
+# (dst, src) pairs into padded (B, E) edge lists and runs eqs. 6-7 with
+# segment reductions — identical math on the real edges (``forward``'s
 # masked pairs contribute exact zeros), at E/N^2 of the pair work.
 
 def sweep_sparse_totals(params: Dict, flat: Dict, edge_dst: jax.Array,

@@ -7,14 +7,17 @@ attach P/H summary nodes, run propagation for EVERY candidate scale-out in
 the valid range, and pick the configuration that best complies with the
 runtime target (smallest scale-out among the feasible; else the argmin).
 
-The default :meth:`EnelScaler.recommend` is the *batched candidate-sweep*
-engine: the graph builder is probed twice per remaining component to derive
-ONE candidate-invariant template (context, metrics, adjacency, masks) plus
-per-candidate delta arrays (a_raw, z_raw, r, H-summary attributes), and the
-full candidate axis is evaluated inside a single jit
-(:func:`repro.core.model.sweep_per_component`).  The original
-per-candidate-graph implementation is kept as :meth:`recommend_pergraph`
-for benchmarking and as a numerical reference.
+Every decision takes one path: :meth:`EnelScaler.prepare_request` probes
+the graph builder twice per remaining component to derive ONE
+candidate-invariant template (context, metrics, adjacency, masks) plus
+per-candidate delta arrays (a_raw, z_raw, r, H-summary attributes), pads
+them to the shape ladders and hands them to a
+:class:`~repro.core.service.DecisionService`, which evaluates the full
+candidate axis in one jit with the sparse-edge engine
+(:func:`repro.core.model.sweep_sparse_totals`).  A fleet batches many
+requests per dispatch; :meth:`EnelScaler.recommend` answers one job through
+a private service.  The original per-candidate-graph implementation is kept
+as :meth:`recommend_pergraph`, the plain reference the tests compare with.
 
 Builder contract for the batched path: ``a``/``z`` may flow *unchanged* into
 node start/end scale-outs (identity only — derived values like (a+z)/2 keep
@@ -32,25 +35,21 @@ when exact per-candidate contexts are required.
 """
 from __future__ import annotations
 
-import dataclasses
 import itertools
 import time
-from collections import OrderedDict, defaultdict
+from collections import defaultdict
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
 from repro.core.bell import BellModel, initial_scaleout
-from repro.core.fallback import FallbackPolicy
 from repro.core.graph import (CTX_DIM, N_METRICS, ComponentGraph, NodeAttrs,
                               SWEEP_KEYS, SweepTemplate, bucket_sweep,
                               historical_summaries_batch, historical_summary,
                               propagation_depth, summary_node, sweep_edge_list)
-from repro.core.model import pick_candidate
-from repro.core.service import DecisionRequest, DecisionResult
+from repro.core.service import (DecisionRequest, DecisionResult,
+                                DecisionService)
 from repro.core.training import EnelTrainer
 
 # graph_builder(comp_idx, a, z, predecessors) -> ComponentGraph with
@@ -65,105 +64,6 @@ Z_PROBE = 2.0e5
 H_SLOT = "__H__"          # placeholder name marking the H-summary node slot
 
 _request_ids = itertools.count()    # DecisionRequest.rid allocator
-
-
-class _TemplateDeviceCache:
-    """Device-resident sweep-template reuse ACROSS decision points of the
-    single-job :meth:`EnelScaler.recommend` (service requests hand their
-    template to the service on the host instead).
-
-    The template base arrays (K, N, ...) are candidate-invariant and change
-    little between decision points with the same remaining-component count
-    (across runs they are often identical): only the entries derived from
-    the current scale-out or the latest summaries move.  One device copy is
-    kept per (remaining components, node slots, candidate count) key, and a
-    per-key host diff re-ships ONLY the arrays whose values changed — the
-    small per-candidate deltas are still rebuilt and shipped every decision
-    (they are donated to the sweep jit off-CPU, so they must be fresh).
-
-    The cache is a bounded LRU over keys (default 8 slots) so a long
-    multi-job campaign cannot grow device memory without limit; with shape
-    bucketing a whole campaign visits only a handful of keys anyway.
-    """
-
-    _ids = itertools.count()        # obs label allocator
-
-    def __init__(self, max_slots: int = 8):
-        self.max_slots = max_slots
-        self._slots: "OrderedDict[Tuple[int, int, int], Tuple[Dict, Dict]]" \
-            = OrderedDict()
-        # upload/skip/eviction counters: registry-backed behind the
-        # original attribute API (properties installed below)
-        reg = obs.registry()
-        name = f"tc{next(self._ids)}"
-        self._obs_counters = {
-            "transfers": reg.counter("enel_template_cache_transfers_total",
-                                     "device uploads performed"),
-            "skips": reg.counter("enel_template_cache_skips_total",
-                                 "uploads avoided by the host diff"),
-            "evictions": reg.counter("enel_template_cache_evictions_total",
-                                     "LRU slots dropped"),
-        }
-        self._obs_counters = {k: v.labels(cache=name)
-                              for k, v in self._obs_counters.items()}
-
-    def adopt(self, template: SweepTemplate, n_candidates: int
-              ) -> SweepTemplate:
-        """Return ``template`` with ``base``/``h_onehot`` swapped for cached
-        device arrays (uploading only what changed since last decision)."""
-        k, n = template.base["mask"].shape
-        key = (k, n, n_candidates)
-        host_new = dict(template.base, __h_onehot__=template.h_onehot)
-        slot = self._slots.get(key)
-        if slot is None:
-            dev = {kk: jnp.asarray(v) for kk, v in host_new.items()}
-            self._slots[key] = ({kk: v.copy() for kk, v in host_new.items()},
-                                dev)
-            self.transfers += len(host_new)
-            while len(self._slots) > self.max_slots:
-                self._slots.popitem(last=False)
-                self.evictions += 1
-        else:
-            self._slots.move_to_end(key)
-            host, dev = slot
-            for kk, v in host_new.items():
-                if np.array_equal(host[kk], v):
-                    self.skips += 1
-                    continue
-                dev[kk] = jnp.asarray(v)
-                host[kk] = v.copy()
-                self.transfers += 1
-        _, dev = self._slots[key]
-        return dataclasses.replace(
-            template, base={kk: dev[kk] for kk in template.base},
-            h_onehot=dev["__h_onehot__"])
-
-
-def _install_cache_counter_properties():
-    def make(attr):
-        def fget(self):
-            return int(self._obs_counters[attr].value)
-
-        def fset(self, value):
-            self._obs_counters[attr].set(value)
-        return property(fget, fset)
-
-    for attr in ("transfers", "skips", "evictions"):
-        setattr(_TemplateDeviceCache, attr, make(attr))
-
-
-_install_cache_counter_properties()
-
-
-# one device-side reduction + compliant pick over the sweep output; the
-# host then fetches (picked index, per-candidate totals) in a single
-# transfer instead of one float() sync per candidate
-def _totals_pick_impl(per_comp, cand, cand_valid, elapsed, target):
-    totals = per_comp.sum(axis=1) + elapsed
-    return pick_candidate(cand, cand_valid, totals, target), totals
-
-
-_totals_pick = jax.jit(_totals_pick_impl)
 
 
 class EnelScaler:
@@ -181,14 +81,9 @@ class EnelScaler:
         # (held as a DecisionResult — device-resident, transferred lazily)
         self.last_candidates: List[int] = []
         self._last_result: Optional[DecisionResult] = None
-        # device-resident template arrays reused across decision points of
-        # the single-job recommend() (service requests carry host templates)
-        self.template_cache = _TemplateDeviceCache()
-        # guardrail backstop for the single-job recommend() path (the fleet
-        # service carries its own policy): non-finite sweep totals never
-        # reach a pick — the bounded model-free clamp answers instead
-        self.fallback = FallbackPolicy()
-        self.fallback_decisions = 0
+        # the single-job recommend()'s own service (made on first use); its
+        # guardrail and fallback policy answer a poisoned model
+        self._service: Optional[DecisionService] = None
         # probe-derived structural masks per (comp idx, #predecessors): the
         # A/Z probe only reveals which node slots track the builder's a/z
         # arguments and the a != z time fractions — structural facts that a
@@ -203,8 +98,7 @@ class EnelScaler:
 
     def copy(self, trainer: EnelTrainer) -> "EnelScaler":
         """A scaler serving ``trainer`` with this one's observation history
-        (summaries, first-component pairs, structural probes); its device
-        template cache starts empty."""
+        (summaries, first-component pairs, structural probes)."""
         out = EnelScaler(trainer, self.range, self.beta,
                          self.candidate_stride)
         out.hist_summaries = defaultdict(
@@ -354,37 +248,19 @@ class EnelScaler:
                   target_runtime: float,
                   current_summary: Optional[NodeAttrs] = None
                   ) -> Tuple[int, float, Dict[int, float]]:
-        """Batched sweep: returns (scaleout, predicted_total, per-cand totals)."""
-        candidates = self.candidate_scaleouts(current_scaleout)
-        if next_comp >= n_components:
-            return current_scaleout, elapsed, {}
-        template, deltas = self.build_sweep(
+        """One job's decision as a one-request :meth:`DecisionService.decide`
+        on this scaler's own service: returns (scaleout, predicted_total,
+        per-candidate totals)."""
+        req = self.prepare_request(
             graph_builder=graph_builder, next_comp=next_comp,
-            n_components=n_components, current_scaleout=current_scaleout,
-            candidates=candidates, current_summary=current_summary)
-        template = self.template_cache.adopt(template, len(candidates))
-        per_dev = self.trainer.predict_sweep_device(template, deltas)  # (C, K)
-        cand_arr = np.array(candidates, np.float32)
-        idx_dev, totals_dev = _totals_pick(
-            per_dev, cand_arr, np.ones(len(candidates), bool),
-            np.float32(elapsed), np.float32(target_runtime))
-        # single host transfer: the pick + the per-candidate totals
-        idx, totals_np = jax.device_get((idx_dev, totals_dev))
-        if not np.isfinite(totals_np).all():    # guardrail: poisoned model
-            self.fallback_decisions += 1
-            best, pred = self.fallback.decide(
-                candidates, totals_np, current_scaleout, elapsed,
-                target_runtime)
-            totals = {s: float(t) for s, t in zip(candidates, totals_np)
-                      if np.isfinite(t)}
-            return best, pred, totals
-        totals = {s: float(totals_np[i]) for i, s in enumerate(candidates)}
-        best = candidates[int(idx)]
-        self._note_sweep(candidates, DecisionResult(
-            scaleout=best, predicted=totals[best], totals=totals,
-            per_component_dev=per_dev, n_candidates=per_dev.shape[0],
-            n_components=per_dev.shape[1]))
-        return best, totals[best], totals
+            n_components=n_components, elapsed=elapsed,
+            current_scaleout=current_scaleout, target_runtime=target_runtime,
+            current_summary=current_summary)
+        if req is None:
+            return current_scaleout, elapsed, {}
+        if self._service is None:
+            self._service = DecisionService()
+        return self.apply_decision(req, self._service.decide([req])[0])
 
     # ------------------------------------------------- fleet decision service
     def prepare_request(self, *, graph_builder: GraphBuilder, next_comp: int,
@@ -396,8 +272,8 @@ class EnelScaler:
         """Build this job's pending decision as a shape-bucketed request for
         :class:`repro.core.service.DecisionService`.
 
-        The sweep is assembled exactly as :meth:`recommend` would, then
-        padded to the fixed shape ladders (padded components read out as
+        The sweep is assembled by :meth:`build_sweep`, then padded to the
+        fixed shape ladders (padded components read out as
         exactly 0 and padded candidates are masked from the pick) and the
         real edges are gathered for the sparse engine.  The template stays
         in host arrays: the service uploads it once per decision group.

@@ -14,8 +14,7 @@ Two fit routes share the loss/optimizer math:
   incrementally by the runner), with metric dropout sampled on-device PER
   STEP inside the scanned Adam loop (fresh mask each step, no 2x batch) and
   per-slot weights selecting the scratch window vs. the newest run.  Both
-  differentiate through ``forward_stacked`` and so honour the fused
-  graph-prop kernel flag (custom VJP).
+  differentiate through ``forward_stacked``.
 """
 from __future__ import annotations
 
@@ -51,15 +50,14 @@ def _huber(err: jax.Array, delta: float = HUBER_DELTA) -> jax.Array:
     return jnp.where(a <= delta, 0.5 * err * err, delta * (a - 0.5 * delta))
 
 
-def enel_loss(params: Dict, batch: Dict, weights: Optional[jax.Array] = None,
-              use_kernel: bool = False) -> Tuple[jax.Array, Dict]:
+def enel_loss(params: Dict, batch: Dict, weights: Optional[jax.Array] = None
+              ) -> Tuple[jax.Array, Dict]:
     """Training loss over a stacked graph batch.
 
     ``weights`` (B,) 0/1 scales each graph's contribution (ring-buffer slots
-    outside the training window); ``use_kernel`` routes eqs. 6-7 through the
-    fused Pallas kernel + its custom VJP (resolve the flag before jitting).
+    outside the training window).
     """
-    out = enel_model.forward_stacked(params, batch, use_kernel=use_kernel)
+    out = enel_model.forward_stacked(params, batch)
     rt_mask = batch["runtime_valid"] & batch["mask"] & ~batch["is_summary"]
     rt_err = jnp.where(rt_mask, out["runtime"] - batch["runtime"], 0.0)
 
@@ -88,13 +86,13 @@ def enel_loss(params: Dict, batch: Dict, weights: Optional[jax.Array] = None,
     return loss, {"runtime": l_rt, "overhead": l_ov, "metrics": l_m}
 
 
-def _adam_update(params, opt, batch, lr, weights=None, use_kernel=False):
+def _adam_update(params, opt, batch, lr, weights=None):
     """One guarded Adam step: a step whose loss or gradients come back
     non-finite is SKIPPED (params/opt unchanged, ``ok=False``) instead of
     writing NaN into the parameters — one poisoned batch row or a
     divergent step can no longer destroy the model."""
     (loss, parts), g = jax.value_and_grad(enel_loss, has_aux=True)(
-        params, batch, weights, use_kernel)
+        params, batch, weights)
     ok = jnp.isfinite(loss)
     for leaf in jax.tree_util.tree_leaves(g):
         ok = ok & jnp.all(jnp.isfinite(leaf))
@@ -116,12 +114,12 @@ def _adam_update(params, opt, batch, lr, weights=None, use_kernel=False):
         (sel(mu, mu0), sel(nu, nu0), jnp.where(ok, t, t0)), loss, ok
 
 
-def _adam_run_impl(params, opt, batch, steps, lr, use_kernel=False):
+def _adam_run_impl(params, opt, batch, steps, lr):
     """`steps` Adam updates fused into one jit (dispatch-bound otherwise);
     also returns how many steps the non-finite guard skipped."""
     def body(carry, _):
         p, o = carry
-        p, o, loss, ok = _adam_update(p, o, batch, lr, None, use_kernel)
+        p, o, loss, ok = _adam_update(p, o, batch, lr)
         return (p, o), (loss, ok)
 
     (params, opt), (losses, oks) = jax.lax.scan(body, (params, opt), None,
@@ -132,12 +130,12 @@ def _adam_run_impl(params, opt, batch, steps, lr, use_kernel=False):
 # params/opt are replaced by the returned pytrees every call -> donating their
 # buffers avoids a copy per fit.  Nothing may keep the old trainer.params /
 # opt leaves for later use: they are deleted by the call.
-_adam_run = jax.jit(_adam_run_impl, static_argnums=(3, 5),
+_adam_run = jax.jit(_adam_run_impl, static_argnums=(3,),
                     donate_argnums=(0, 1))
 
 
 def _adam_run_resident_impl(params, opt, batch, weights, key, lr, dropout_p,
-                            steps, use_kernel):
+                            steps):
     """Scanned Adam over a resident batch with PER-STEP metric dropout.
 
     Each step samples a fresh on-device mask hiding task-set metrics with
@@ -151,7 +149,7 @@ def _adam_run_resident_impl(params, opt, batch, weights, key, lr, dropout_p,
         drop = (jax.random.uniform(sub, batch["metrics_valid"].shape)
                 < dropout_p) & ~batch["is_summary"]
         b = dict(batch, metrics_valid=batch["metrics_valid"] & ~drop)
-        p, o, loss, ok = _adam_update(p, o, b, lr, weights, use_kernel)
+        p, o, loss, ok = _adam_update(p, o, b, lr, weights)
         return (p, o, k), (loss, ok)
 
     (params, opt, _), (losses, oks) = jax.lax.scan(body, (params, opt, key),
@@ -161,7 +159,7 @@ def _adam_run_resident_impl(params, opt, batch, weights, key, lr, dropout_p,
 
 # batch/weights live in the TrainingCache and MUST NOT be donated; params/opt
 # follow the same replace-every-call (donated) pattern as the legacy run.
-_adam_run_resident = jax.jit(_adam_run_resident_impl, static_argnums=(7, 8),
+_adam_run_resident = jax.jit(_adam_run_resident_impl, static_argnums=(7,),
                              donate_argnums=(0, 1))
 
 
@@ -276,8 +274,7 @@ class EnelTrainer:
         batch = {k: jnp.asarray(v) for k, v in stacked.items()}
         steps = _round_steps(steps)
         self.params, self.opt, loss, skipped = _adam_run(
-            self.params, self.opt, batch, steps, self.lr,
-            enel_model.graph_prop_kernel_enabled())
+            self.params, self.opt, batch, steps, self.lr)
         self._note_skipped(skipped, steps)
         loss = float(loss)
         self._emit_fit("legacy", from_scratch, steps, loss)
@@ -339,11 +336,10 @@ class EnelTrainer:
         key = jax.random.fold_in(jax.random.PRNGKey(self.seed ^ 0x5eed),
                                  self._fit_calls)
         self._fit_calls += 1
-        use_kernel = enel_model.graph_prop_kernel_enabled()
         n_steps = _round_steps(steps)
         self.params, self.opt, loss, skipped = _adam_run_resident(
             self.params, self.opt, batch, jnp.asarray(weights), key, self.lr,
-            float(metric_dropout), n_steps, use_kernel)
+            float(metric_dropout), n_steps)
         self._note_skipped(skipped, n_steps)
         if self.last_skipped_steps >= n_steps and _retry and \
                 self.params_finite() and \
@@ -421,32 +417,6 @@ class EnelTrainer:
         """Totals for an already-stacked (B, N, ...) graph-array dict."""
         dev = {k: jnp.asarray(v) for k, v in batch.items()}
         return np.asarray(enel_model.predict_total_runtime(self.params, dev))
-
-    def predict_sweep_device(self, template, deltas: Dict[str, np.ndarray],
-                             use_kernel: bool = None) -> jax.Array:
-        """Batched candidate-sweep predictions as a DEVICE (C, K) array.
-
-        One device transfer + one jit call per decision: the template's
-        (K, N, ...) base arrays and the small (C, K, ...) delta arrays are
-        shipped as-is and evaluated via
-        :func:`repro.core.model.sweep_per_component` with the propagation
-        depth lowered to the template DAG's actual depth.  No host sync —
-        callers reduce/pick on device and fetch once.
-        """
-        levels = min(enel_model.MAX_LEVELS, max(1, template.levels))
-        return enel_model.sweep_per_component(
-            self.params,
-            {k: jnp.asarray(v) for k, v in template.base.items()},
-            jnp.asarray(template.h_onehot),
-            {k: jnp.asarray(np.asarray(v)) for k, v in deltas.items()},
-            use_kernel=use_kernel, levels=levels)
-
-    def predict_sweep(self, template, deltas: Dict[str, np.ndarray],
-                      use_kernel: bool = None) -> np.ndarray:
-        """Host (C, K) sweep predictions (reference/tests; one transfer)."""
-        n_cand, n_rem = deltas["a_raw"].shape[:2]
-        per = self.predict_sweep_device(template, deltas, use_kernel)
-        return np.asarray(per)[:n_cand, :n_rem]
 
 
 def _install_counter_properties():

@@ -793,7 +793,6 @@ class FleetCampaign:
             cache.latest = ((cache.pos - nc + np.arange(nc))
                             % cache.capacity).astype(np.int64)
             exp._run_idx += n_runs
-            exp.enel.fallback_decisions += int(carry["fallbacks"][j])
             for r in range(n_runs):
                 exp.stats.append(stats[r][j])
             st = exp.backend.slot_state(exp.sim_slot)

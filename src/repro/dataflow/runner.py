@@ -68,10 +68,6 @@ class RunStats:
     fit_seconds: float = 0.0
     decide_seconds: float = 0.0
     decide_calls: int = 0
-    # sweep-template device-cache traffic during this run (LRU-bounded)
-    cache_transfers: int = 0
-    cache_skips: int = 0
-    cache_evictions: int = 0
     # fault-tolerance counters: decisions answered by the model-free
     # fallback / shed under overload during this run, plus this run's share
     # of service-wide dispatch retries and breaker trips (deltas over the
@@ -265,7 +261,7 @@ class JobExperiment:
         """Everything a trace-identical resume needs: learned state (model
         params, optimizer moments, cache rings, observation histories), the
         sim slot's RNG/clock state and the bookkeeping counters.  Perf-only
-        caches (sweep templates, probe masks, memoized stacks) are skipped —
+        caches (edge lists, candidate arrays, memoized stacks) are skipped —
         they repopulate deterministically.  Graph/summary lists hold
         append-only immutable records, so shallow list copies suffice."""
         return {
@@ -285,7 +281,6 @@ class JobExperiment:
                                    self.enel.hist_summaries.items()},
                 "first_component_history":
                     list(self.enel.first_component_history),
-                "fallback_decisions": int(self.enel.fallback_decisions),
                 # NOT perf-only: a probe-cache MISS makes build_sweep call
                 # the graph builder twice more, consuming encoder rng draws
                 # — the hit/miss pattern must replay exactly (entries are
@@ -319,8 +314,6 @@ class JobExperiment:
                    state["enel"]["hist_summaries"].items()})
         self.enel.first_component_history = \
             list(state["enel"]["first_component_history"])
-        self.enel.fallback_decisions = \
-            int(state["enel"]["fallback_decisions"])
         self.enel._probe_cache = dict(state["enel"]["probe_cache"])
         self.ellis.history = defaultdict(
             list, {k: list(v) for k, v in state["ellis_history"].items()})
@@ -458,8 +451,6 @@ class JobExperiment:
         """Generator form of :meth:`adaptive_run` for fleet interleaving."""
         assert self.target is not None, "profile() first"
         job = self.job
-        cache = self.enel.template_cache
-        cache0 = (cache.transfers, cache.skips, cache.evictions)
         # retry/breaker deltas are service-wide (one envelope serves the
         # whole fleet); per-run rows report the delta observed over the run
         svc0 = (self.service.retries, self.service.breaker_trips)
@@ -500,9 +491,6 @@ class JobExperiment:
                       n_rescales=len(run.rescales),
                       fit_seconds=fit_s, decide_seconds=decide_s,
                       decide_calls=decide_n,
-                      cache_transfers=cache.transfers - cache0[0],
-                      cache_skips=cache.skips - cache0[1],
-                      cache_evictions=cache.evictions - cache0[2],
                       fallback_decisions=fallback_n, shed_requests=shed_n,
                       retries=self.service.retries - svc0[0],
                       breaker_trips=self.service.breaker_trips - svc0[1])

@@ -7,7 +7,8 @@ Contracts under test:
   elapsed times and targets (property-tested when hypothesis is
   available, seeded-sweep otherwise);
 * NaN-poisoned model parameters trip the on-device guardrail and the
-  service answers from the fallback policy — never a non-finite pick;
+  service answers from the fallback policy — never a non-finite pick —
+  for fleet requests and for the single-job ``recommend`` alike;
 * exhausted dispatch retries degrade a whole group to fallback decisions
   and feed the circuit breaker through its CLOSED -> OPEN -> HALF_OPEN ->
   CLOSED lifecycle;
@@ -109,7 +110,7 @@ def profiled_exp():
     return exp
 
 
-def _request(exp, seed=500):
+def _decision_kwargs(exp, seed=500):
     job = exp.job
     builder = lambda ci, a, z, pr: _to_graph(
         _future_nodes(exp.encoder, job, ci, a, z), pr, ci)
@@ -118,10 +119,14 @@ def _request(exp, seed=500):
                                  failures_log=[])
     summ = summary_node(_component_nodes(exp.encoder, job, comp), name="P0")
     exp.encoder.rng = np.random.RandomState(seed)
-    return exp.enel.prepare_request(
-        graph_builder=builder, next_comp=1, n_components=job.n_components,
-        elapsed=comp.runtime, current_scaleout=8,
-        target_runtime=exp.target, current_summary=summ)
+    return dict(graph_builder=builder, next_comp=1,
+                n_components=job.n_components, elapsed=comp.runtime,
+                current_scaleout=8, target_runtime=exp.target,
+                current_summary=summ)
+
+
+def _request(exp, seed=500):
+    return exp.enel.prepare_request(**_decision_kwargs(exp, seed))
 
 
 # ------------------------------------------------ guardrail: poisoned model
@@ -140,6 +145,25 @@ def test_guardrail_nan_params_falls_back(profiled_exp):
     # the same request with healthy params is a model decision again
     res2 = svc.decide([req])[0]
     assert not res2.fallback and math.isfinite(res2.predicted)
+
+
+@pytest.mark.parametrize("poison", [float("nan"), float("inf")])
+def test_recommend_nonfinite_model_takes_bounded_fallback(profiled_exp,
+                                                          poison):
+    """A poisoned single-job model: recommend() answers from the service's
+    bounded fallback policy, counted in that service's fallback
+    decisions, and never picks outside the candidates."""
+    exp = profiled_exp
+    trainer = exp.trainer.copy(exp.seed)
+    trainer.params = jax.tree_util.tree_map(
+        lambda p: jnp.full_like(p, poison), trainer.params)
+    scaler = exp.enel.copy(trainer)
+    kw = _decision_kwargs(exp)
+    s, pred, totals = scaler.recommend(**kw)
+    assert s in scaler.candidate_scaleouts(kw["current_scaleout"])
+    assert all(math.isfinite(t) for t in totals.values())
+    assert scaler._service.guardrail_trips == 1
+    assert scaler._service.fallback_decisions == 1
 
 
 # ------------------------------- retry exhaustion + circuit breaker lifecycle

@@ -2,14 +2,16 @@
 
 The contracts under test:
 
-* padding a sweep to the bucket ladders changes NOTHING — the padded dense
-  sweep equals the unpadded one bit-for-bit on the real JOBS builders;
-* the sparse-edge engine equals the dense engine on random masked DAGs;
+* padding a sweep to the bucket ladders changes nothing — the padded
+  sparse sweep equals the per-graph prediction of every materialised
+  candidate on the real JOBS builders, and padded components read exactly 0;
+* the sparse-edge engine equals the dense per-graph engine on random
+  masked DAGs;
 * one batched service dispatch over a multi-job fleet returns exactly the
-  decisions the jobs would get from sequential per-job ``recommend``;
-* the template device cache is a bounded LRU;
-* requests hand their template to the service as host arrays, decide
-  exactly as with a device-resident template, and upload nothing in prep;
+  decisions the jobs would get from sequential per-job ``recommend``, which
+  is itself one one-request ``decide``;
+* requests hand their template to the service as host arrays and upload
+  nothing in prep;
 * a group's request stack is one compiled call per memo field, equal to
   the per-leaf stack bit for bit and compiled once per job rung;
 * the on-device pick replicates the host pick's tie-breaking.
@@ -25,12 +27,12 @@ import pytest
 
 from repro.core import model as enel_model
 from repro.core.graph import (CTX_DIM, N_METRICS, NodeAttrs, SweepTemplate,
-                              bucket_sweep, build_graph, stack_graphs,
-                              summary_node, sweep_edge_list)
+                              bucket_sweep, build_graph, materialize_candidate,
+                              stack_graphs, summary_node, sweep_edge_list)
 from repro.core.model import pick_candidate, sweep_sparse_totals
-from repro.core.scaling import EnelScaler, _TemplateDeviceCache
+from repro.core.scaling import EnelScaler
 from repro.core import service as service_mod
-from repro.core.service import DecisionService
+from repro.core.service import DecisionService, sweep_eval_one
 from repro.dataflow import FleetCampaign, JobExperiment
 from repro.dataflow.runner import (_component_nodes, _future_nodes, _to_graph)
 
@@ -64,12 +66,17 @@ def _decision_kwargs(exp):
                 current_summary=summ)
 
 
-# ------------------------------------------------- padded == unpadded (0.0)
+# ---------------------------------------------- padded sweep == per-graph
+_sweep_one = jax.jit(sweep_eval_one, static_argnums=(11,))
+
+
 @pytest.mark.parametrize("job_key", ["lr", "mpc", "kmeans", "gbt"])
 def test_bucketed_sweep_matches_unpadded_exactly(job_key):
-    """Dense sweep on ladder-padded template/deltas == unpadded sweep with
-    0.0 deviation, on the real JOBS builders, across K/C shapes that cross
-    the bucket boundaries (incl. exact-rung K and small tails)."""
+    """The sparse sweep on ladder-padded template/deltas == the per-graph
+    prediction of every unpadded materialised candidate (up to float
+    summation order), and padded components read exactly 0, on the real
+    JOBS builders, across K/C shapes that cross the bucket boundaries
+    (incl. exact-rung K and small tails)."""
     exp = JobExperiment(job_key, seed=7)
     job = exp.job
     builder = lambda ci, a, z, pr: _to_graph(
@@ -93,18 +100,21 @@ def test_bucketed_sweep_matches_unpadded_exactly(job_key):
         template, deltas = exp.enel.build_sweep(
             graph_builder=builder, next_comp=next_comp, n_components=n,
             current_scaleout=9, candidates=candidates)
-        ref = exp.enel.trainer.predict_sweep(template, deltas)
         padded_t, padded_d, (c_real, k_real) = bucket_sweep(template, deltas)
-        assert padded_d["a_raw"].shape[0] >= c_real
+        c_b = padded_d["a_raw"].shape[0]
+        assert c_b >= c_real
         assert padded_t.base["mask"].shape[0] >= k_real
-        per = enel_model.sweep_per_component(
-            exp.enel.trainer.params,
-            {k: jnp.asarray(v) for k, v in padded_t.base.items()},
-            jnp.asarray(padded_t.h_onehot),
-            {k: jnp.asarray(v) for k, v in padded_d.items()},
-            use_kernel=False, levels=padded_t.levels)
+        _, _, per, _ = _sweep_one(
+            exp.enel.trainer.params, padded_t.base, padded_t.h_onehot,
+            padded_d, *sweep_edge_list(padded_t.base), jnp.zeros(c_b),
+            jnp.ones(c_b, bool), 0.0, 0.0, padded_t.levels)
         got = np.asarray(per)[:c_real, :k_real]
-        np.testing.assert_array_equal(got, ref)       # 0.0 deviation
+        graphs = [materialize_candidate(template, deltas, c)
+                  for c in range(c_real)]
+        ref = exp.enel.trainer.predict_stacked(
+            {key: np.concatenate([g[key] for g in graphs])
+             for key in graphs[0]}).reshape(c_real, k_real)
+        np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-6)
         # padded components must read out EXACTLY 0
         tail = np.asarray(per)[:, k_real:]
         np.testing.assert_array_equal(tail, np.zeros_like(tail))
@@ -135,8 +145,8 @@ def test_sparse_engine_matches_dense(seed):
     batch = stack_graphs(graphs)
     params = enel_model.init_enel(jax.random.PRNGKey(seed))
     dense = enel_model.forward_stacked(
-        params, {k: jnp.asarray(v) for k, v in batch.items()},
-        use_kernel=False)["total_runtime"]
+        params, {k: jnp.asarray(v) for k, v in batch.items()}
+    )["total_runtime"]
     dst, src, val = sweep_edge_list(batch)
     sparse = sweep_sparse_totals(
         params, {k: jnp.asarray(v) for k, v in batch.items()},
@@ -156,7 +166,7 @@ def test_service_matches_sequential_recommend(fleet_exps):
         exp.enel.prepare_request(**kw)
     sequential, requests = [], []
     for i, (exp, kw) in enumerate(zip(fleet_exps, kwargs)):
-        # identical encoder RNG draws for both engines' graph builds
+        # identical encoder RNG draws for both paths' graph builds
         exp.encoder.rng = np.random.RandomState(1000 + i)
         sequential.append(exp.enel.recommend(**kw))
         exp.encoder.rng = np.random.RandomState(1000 + i)
@@ -172,6 +182,33 @@ def test_service_matches_sequential_recommend(fleet_exps):
                                        rtol=1e-4, atol=1e-3)
         np.testing.assert_allclose(res.predicted, tot_seq,
                                    rtol=1e-4, atol=1e-3)
+
+
+@pytest.fixture(scope="module")
+def table2_exps(fleet_exps):
+    """The four Table II jobs, profiled: the fleet's three and MPC."""
+    mpc = JobExperiment("mpc", seed=23)
+    mpc.profile(2)
+    return {exp.job_key: exp for exp in list(fleet_exps) + [mpc]}
+
+
+@pytest.mark.parametrize("job_key", ["lr", "mpc", "kmeans", "gbt"])
+def test_recommend_is_one_request_decide(table2_exps, job_key):
+    """recommend() is exactly a one-request decide on a service of its
+    own: the same scale-out, prediction and totals, and its per-component
+    diagnostics are the result's."""
+    exp = table2_exps[job_key]
+    kw = _decision_kwargs(exp)
+    exp.enel.prepare_request(**kw)              # warm the probe cache
+    exp.encoder.rng = np.random.RandomState(4000)
+    s, predicted, totals = exp.enel.recommend(**kw)
+    per = exp.enel.last_per_component
+    exp.encoder.rng = np.random.RandomState(4000)
+    res = DecisionService().decide([exp.enel.prepare_request(**kw)])[0]
+    assert not res.fallback
+    assert (s, predicted, totals) == (res.scaleout, res.predicted,
+                                      res.totals)
+    np.testing.assert_array_equal(per, res.per_component)
 
 
 def test_result_per_component_lazy_shape(fleet_exps):
@@ -196,13 +233,12 @@ def test_fleet_campaign_round_batches(fleet_exps):
     for st, exp in zip(stats, fleet_exps):
         assert st.kind == "enel" and st.runtime > 0
         assert st.decide_calls > 0
-        assert st.cache_transfers >= 0 and st.cache_skips >= 0
         assert exp.stats[-1] is st
     assert campaign.service.batched_away > 0      # real cross-job batching
     assert campaign.service.decisions == sum(st.decide_calls for st in stats)
 
 
-# ----------------------------------------------------------- LRU bound
+# ------------------------------------------------------- mini templates
 def _mini_template(k, n=4, seed=0):
     rng = np.random.RandomState(seed)
     base = {
@@ -221,22 +257,6 @@ def _mini_template(k, n=4, seed=0):
                          a_follows_a=flags, a_follows_z=flags,
                          z_follows_a=flags, z_follows_z=flags,
                          r_eq=base["r"], r_neq=base["r"])
-
-
-def test_template_device_cache_lru_eviction():
-    cache = _TemplateDeviceCache(max_slots=2)
-    for k in (2, 3, 4):
-        cache.adopt(_mini_template(k), n_candidates=6)
-    assert len(cache._slots) == 2
-    assert cache.evictions == 1
-    # re-adopting an evicted key re-uploads (it was dropped)
-    before = cache.transfers
-    cache.adopt(_mini_template(2), n_candidates=6)
-    assert cache.transfers > before
-    assert cache.evictions == 2
-    # touching a live key keeps it resident (LRU order, no new eviction)
-    cache.adopt(_mini_template(2), n_candidates=6)
-    assert cache.evictions == 2
 
 
 # ------------------------------------------- double-buffered dispatch parity
@@ -265,50 +285,10 @@ def test_double_buffered_dispatch_matches_sync(fleet_exps):
 
 
 # ------------------------------------------------ host templates in requests
-@pytest.mark.parametrize("double_buffer", [False, True])
-@pytest.mark.parametrize("width", [1, 3])
-def test_host_template_matches_device_template(fleet_exps, width,
-                                               double_buffer):
-    """Requests hand ``base``/``h_onehot`` to the service as host arrays;
-    a group of them decides exactly as the same requests with the template
-    adopted into a device cache (the service then stacks device leaves)."""
-    exp = fleet_exps[0]
-    kw = _decision_kwargs(exp)
-    exp.enel.prepare_request(**kw)              # warm probe caches
-    reqs = []
-    for i in range(width):
-        exp.encoder.rng = np.random.RandomState(3000 + i)
-        reqs.append(exp.enel.prepare_request(
-            **dict(kw, elapsed=kw["elapsed"] + i)))
-    cache = _TemplateDeviceCache()
-    dev_reqs = []
-    for req in reqs:
-        assert isinstance(req.h_onehot, np.ndarray)
-        assert all(isinstance(v, np.ndarray) for v in req.base.values())
-        flags = np.zeros(req.h_onehot.shape, bool)
-        adopted = cache.adopt(SweepTemplate(
-            base=req.base, h_onehot=req.h_onehot, a_follows_a=flags,
-            a_follows_z=flags, z_follows_a=flags, z_follows_z=flags,
-            r_eq=req.base["r"], r_neq=req.base["r"]), len(req.candidates))
-        assert isinstance(adopted.h_onehot, jax.Array)
-        dev_reqs.append(dataclasses.replace(
-            req, base=adopted.base, h_onehot=adopted.h_onehot))
-    host_svc = DecisionService(double_buffer=double_buffer)
-    dev_svc = DecisionService(double_buffer=double_buffer)
-    res_h = host_svc.decide(reqs)
-    res_d = dev_svc.decide(dev_reqs)
-    assert host_svc.dispatches == dev_svc.dispatches == 1
-    for a, b in zip(res_h, res_d):
-        assert not a.fallback and not b.fallback
-        assert a.scaleout == b.scaleout
-        assert a.predicted == b.predicted
-        assert a.totals == b.totals
-
-
 def test_lockstep_round_uploads_templates_in_decide(monkeypatch):
-    """One adaptive lockstep round through the service: no tenant's device
-    template cache uploads anything, each prep span reports the template
-    bytes it hands over, and the service stacks those host arrays itself."""
+    """One adaptive lockstep round through the service: each prep span
+    reports the template bytes it hands over, and the service stacks those
+    host arrays itself."""
     from repro import obs
     exps = [JobExperiment(k, seed=80 + i, candidate_stride=4)
             for i, k in enumerate(("lr", "lr", "kmeans"))]
@@ -340,8 +320,7 @@ def test_lockstep_round_uploads_templates_in_decide(monkeypatch):
         exp.enel.prepare_request = recording_prepare(
             exp.enel.prepare_request)
     stats = camp.adaptive_round("enel", inject_failures=False)
-    assert reqs and all(st.cache_transfers == 0 for st in stats)
-    assert all(exp.enel.template_cache.transfers == 0 for exp in exps)
+    assert reqs and len(stats) == len(exps)
     adopt = [sp.attrs["host_bytes"] for sp in spans
              if sp.kind == "enel.prep.adopt"]
     assert len(adopt) == len(reqs) and min(adopt) > 0

@@ -1,9 +1,9 @@
-"""Batched candidate-sweep engine + fused graph-prop kernel correctness.
+"""Batched candidate-sweep engine correctness.
 
-The sweep must reproduce the per-graph predict path exactly: one template per
-remaining component + per-candidate deltas, evaluated in a single jit, equals
-building every (candidate x component) graph and predicting it individually.
-The Pallas kernel must match its pure-numpy ref on random masked DAGs.
+The sweep must reproduce the per-graph predict path: one template per
+remaining component + per-candidate deltas, evaluated by the decision
+service in a single jit, equals building every (candidate x component)
+graph and predicting it individually.
 """
 import jax
 import jax.numpy as jnp
@@ -11,11 +11,12 @@ import numpy as np
 import pytest
 
 from repro.core import model as enel_model
-from repro.core.graph import (CTX_DIM, MAX_NODES, N_METRICS, NodeAttrs,
-                              build_graph, historical_summaries_batch,
-                              historical_summary, materialize_candidate,
-                              propagation_depth, stack_graphs, summary_node)
+from repro.core.graph import (CTX_DIM, N_METRICS, NodeAttrs, build_graph,
+                              historical_summaries_batch, historical_summary,
+                              materialize_candidate, propagation_depth,
+                              stack_graphs, summary_node)
 from repro.core.scaling import EnelScaler
+from repro.core.service import DecisionService
 from repro.core.training import EnelTrainer
 
 RNG = np.random.RandomState(0)
@@ -62,26 +63,36 @@ def scaler():
     return sc
 
 
-def test_sweep_matches_pergraph_predict(scaler):
-    """Batched sweep == per-graph EnelTrainer.predict over every candidate."""
+@pytest.mark.parametrize("next_comp", [0, 2, 4])
+def test_sweep_matches_pergraph_predict(scaler, next_comp):
+    """The service's per-component sweep == per-graph
+    EnelTrainer.predict over every candidate."""
     cands = scaler.candidate_scaleouts(8)
     summ = summary_node(_nodes(1, 8, 8), name="P1")
     template, deltas = scaler.build_sweep(
-        graph_builder=_builder, next_comp=2, n_components=5,
+        graph_builder=_builder, next_comp=next_comp, n_components=5,
         current_scaleout=8, candidates=cands, current_summary=summ)
-    per = scaler.trainer.predict_sweep(template, deltas)
-    assert per.shape == (len(cands), 3)
+    req = scaler.prepare_request(
+        graph_builder=_builder, next_comp=next_comp, n_components=5,
+        elapsed=10.0, current_scaleout=8, target_runtime=25.0,
+        current_summary=summ)
+    per = DecisionService().decide([req])[0].per_component
+    assert per.shape == (len(cands), 5 - next_comp)
     for c in range(len(cands)):
         ref = scaler.trainer.predict_stacked(
             materialize_candidate(template, deltas, c))
         np.testing.assert_allclose(per[c], ref, atol=1e-5)
 
 
-def test_sweep_recommend_matches_pergraph_recommend(scaler):
+@pytest.mark.parametrize("current_scaleout", [4, 8, 20])
+@pytest.mark.parametrize("next_comp", [0, 2, 4])
+def test_sweep_recommend_matches_pergraph_recommend(scaler, next_comp,
+                                                    current_scaleout):
     """With a candidate-invariant-context builder, the batched recommend and
     the original per-candidate-graph path agree on totals and choice."""
-    kw = dict(graph_builder=_builder, next_comp=2, n_components=5,
-              elapsed=10.0, current_scaleout=8, target_runtime=25.0,
+    kw = dict(graph_builder=_builder, next_comp=next_comp, n_components=5,
+              elapsed=10.0, current_scaleout=current_scaleout,
+              target_runtime=25.0,
               current_summary=summary_node(_nodes(1, 8, 8), name="P1"))
     s_new, tot_new, totals_new = scaler.recommend(**kw)
     s_old, tot_old, totals_old = scaler.recommend_pergraph(**kw)
@@ -127,63 +138,9 @@ def test_depth_lowered_levels_are_exact():
     g = build_graph(nodes, [(i, i + 1) for i in range(4)])
     batch = {k: jnp.asarray(v) for k, v in stack_graphs([g]).items()}
     depth = propagation_depth(g.adj, g.mask)
-    full = enel_model.forward_stacked(params, batch, use_kernel=False)
-    low = enel_model.forward_stacked(params, batch, use_kernel=False,
-                                     levels=depth)
+    full = enel_model.forward_stacked(params, batch)
+    low = enel_model.forward_stacked(params, batch, levels=depth)
     np.testing.assert_array_equal(np.asarray(full["metrics"]),
                                   np.asarray(low["metrics"]))
     np.testing.assert_array_equal(np.asarray(full["total_runtime"]),
                                   np.asarray(low["total_runtime"]))
-
-
-# ------------------------------------------------------------ Pallas kernel
-def _random_batch(b, seed):
-    rng = np.random.RandomState(seed)
-    x = rng.randn(b, MAX_NODES, enel_model.X_DIM).astype(np.float32)
-    adj = np.tril(rng.rand(b, MAX_NODES, MAX_NODES) < 0.3, -1)
-    valid = rng.rand(b, MAX_NODES) < 0.5
-    m = rng.rand(b, MAX_NODES, N_METRICS).astype(np.float32)
-    return x, adj, m, valid
-
-
-@pytest.mark.parametrize("b,seed", [(1, 0), (5, 1), (8, 2), (13, 3)])
-def test_graph_prop_kernel_matches_ref(b, seed):
-    from repro.kernels.graph_prop.ops import graph_prop
-    from repro.kernels.graph_prop.ref import graph_prop_ref
-    params = enel_model.init_enel(jax.random.PRNGKey(0))
-    x, adj, m, valid = _random_batch(b, seed)
-    e, mh = graph_prop(params, jnp.asarray(x), jnp.asarray(adj),
-                       jnp.asarray(m), jnp.asarray(valid))
-    np_params = jax.tree_util.tree_map(np.asarray, params)
-    er, mr = graph_prop_ref(np_params, x, adj, m, valid)
-    np.testing.assert_allclose(np.asarray(e), er, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(mh), mr, atol=1e-5)
-
-
-def test_forward_stacked_kernel_flag_matches_inline():
-    """forward_stacked(use_kernel=True) == inline vmap(forward) path."""
-    params = enel_model.init_enel(jax.random.PRNGKey(0))
-    graphs = []
-    for k in range(3):
-        nodes = _nodes(k, 8.0, 16.0, observe=(k == 0))
-        preds = [summary_node(_nodes(k, 8, 8), name=f"P{k}")] if k else []
-        graphs.append(_graph(nodes, preds, k))
-    batch = {k: jnp.asarray(v) for k, v in stack_graphs(graphs).items()}
-    out_inline = enel_model.forward_stacked(params, batch, use_kernel=False)
-    out_kernel = enel_model.forward_stacked(params, batch, use_kernel=True)
-    for key in ("edges", "metrics", "runtime", "acc_runtime",
-                "total_runtime"):
-        np.testing.assert_allclose(np.asarray(out_inline[key]),
-                                   np.asarray(out_kernel[key]),
-                                   atol=1e-5, rtol=1e-5)
-
-
-def test_sweep_with_kernel_flag(scaler):
-    """The sweep path also routes through the kernel behind the flag."""
-    cands = [4, 12, 20, 36]
-    template, deltas = scaler.build_sweep(
-        graph_builder=_builder, next_comp=1, n_components=4,
-        current_scaleout=12, candidates=cands)
-    inline = scaler.trainer.predict_sweep(template, deltas, use_kernel=False)
-    fused = scaler.trainer.predict_sweep(template, deltas, use_kernel=True)
-    np.testing.assert_allclose(inline, fused, atol=1e-5, rtol=1e-5)

@@ -3,8 +3,8 @@
 Nothing runs: each case lowers a jitted program at its real shapes (from
 ``jax.ShapeDtypeStruct``) and compiles it with the TPU compiler for one chip
 of a ``v5e:2x2`` topology that is described, not attached.  That is where
-Mosaic refuses a primitive or a layout, and where a kernel overruns the
-scoped VMEM, both of which interpret mode cannot show.
+the TPU compiler refuses a primitive or a layout, or a program outgrows the
+chip's memory, which a CPU run cannot show.
 
 The topology is described inside a module-scoped fixture (never at import),
 and every case skips from there when it cannot be described.
@@ -18,7 +18,6 @@ from repro.core import model as enel_model
 from repro.core.graph import (CAND_LADDER, COMP_LADDER, CTX_DIM, EDGE_LADDER,
                               LEVEL_LADDER, MAX_NODES, N_METRICS)
 
-SWEEP_B = 1152               # 36 candidates x 32 components
 FLEET = 1024
 
 
@@ -55,25 +54,28 @@ def _param_specs(sharding, lead=()):
                                        sharding=sharding), shapes)
 
 
-@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("direction", ["loss", "adam_step"])
 @pytest.mark.parametrize("n", [8, 16])
-def test_graph_prop_kernel_compiles(one_chip, direction, n):
-    from repro.kernels.graph_prop.ops import graph_prop
+def test_resident_fit_compiles(one_chip, direction, n):
+    """The resident fit over a full history ring of ``n``-slot graphs: the
+    loss alone, and the scanned guarded Adam run that differentiates it."""
+    from repro.core.graph import empty_graph, stack_graphs
+    from repro.core.training import _adam_run_resident, enel_loss
+    from repro.dataflow.runner import HISTORY_WINDOW
     s = _spec(one_chip)
-    args = (_param_specs(one_chip), s((SWEEP_B, n, enel_model.X_DIM)),
-            s((SWEEP_B, n, n), jnp.bool_), s((SWEEP_B, n, N_METRICS)),
-            s((SWEEP_B, n), jnp.bool_))
-
-    def fwd(p, x, adj, m, valid):
-        return graph_prop(p, x, adj, m, valid, levels=8, interpret=False)
-
-    def loss(p, x, adj, m, valid):
-        e, mh = fwd(p, x, adj, m, valid)
-        return jnp.sum(e) + jnp.sum(mh * mh)
-
-    fn = fwd if direction == "forward" else jax.grad(loss, argnums=(0, 1, 3))
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    batch = {k: s((HISTORY_WINDOW,) + v.shape[1:], v.dtype)
+             for k, v in stack_graphs([empty_graph(n)]).items()}
+    weights = s((HISTORY_WINDOW,))
+    params = _param_specs(one_chip)
+    if direction == "loss":
+        compiled = jax.jit(lambda p, b, w: enel_loss(p, b, w)[0]).lower(
+            params, batch, weights).compile()
+    else:
+        opt = (params, params, s((), jnp.int32))
+        compiled = _adam_run_resident.lower(
+            params, opt, batch, weights, s((2,), jnp.uint32), s(()), s(()),
+            32).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
 
 
 def test_fleet_sweep_compiles_at_top_rung(one_chip):
